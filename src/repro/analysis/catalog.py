@@ -8,7 +8,8 @@ out the soundness caveat).  Points are chosen to exercise every
 structural regime of each kernel: single-block and multi-block grids,
 padding (shape not a block multiple), and — for flash attention — GQA
 group folding and both causal modes.  Grids stay tiny (tens to hundreds
-of programs); the blocks are small on purpose.
+of programs).  The graph kernels' vertex blocks are pinned at the TPU's
+1024-element tile, the smallest block Mosaic compiles.
 
 **Declarations.**  ``KERNEL_DECLARATIONS`` maps a kernel *body* (keyed by
 ``(module, qualname)`` — two bodies in this repo share the name
@@ -179,10 +180,12 @@ def _build_sparse_expand(p: dict) -> list[PallasCapture]:
 
 
 KERNEL_CATALOG: tuple[KernelEntry, ...] = (
+    # vertex-blocked kernels: blocks are whole 1024-element TPU tiles
+    # (kernels.tiling), so multi-block and padded points need n > 1024
     KernelEntry("counter_scatter", (
-        {"n": 64, "b": 32, "block_v": 16, "block_u": 8},   # 4×4 grid
-        {"n": 24, "b": 12, "block_v": 16, "block_u": 8},   # padded
-        {"n": 16, "b": 8, "block_v": 16, "block_u": 8},    # single block
+        {"n": 4096, "b": 32, "block_v": 1024, "block_u": 8},   # 4×4 grid
+        {"n": 2500, "b": 12, "block_v": 1024, "block_u": 8},   # padded
+        {"n": 1024, "b": 8, "block_v": 1024, "block_u": 8},    # one block
     ), _build_counter_scatter),
     KernelEntry("segment_reduce", (
         {"m": 64, "d": 8, "segs": 48, "block_e": 16, "block_n": 16},
@@ -195,29 +198,29 @@ KERNEL_CATALOG: tuple[KernelEntry, ...] = (
          "block_q": 8, "block_k": 8, "causal": False},     # MHA, sq != sk
     ), _build_flash),
     KernelEntry("first_live_scan", (
-        {"n": 64, "w": 16, "block_v": 16},
-        {"n": 40, "w": 16, "block_v": 16},                 # padded
+        {"n": 4096, "w": 16, "block_v": 1024},
+        {"n": 2500, "w": 16, "block_v": 1024},             # padded
     ), _build_first_live),
     KernelEntry("frontier_expand", (
-        {"n": 64, "w": 16, "block_v": 16},
-        {"n": 40, "w": 16, "block_v": 16},
+        {"n": 4096, "w": 16, "block_v": 1024},
+        {"n": 2500, "w": 16, "block_v": 1024},
     ), _build_frontier_expand),
     KernelEntry("bucket_peel", (
-        {"n": 64, "block_v": 16},
-        {"n": 40, "block_v": 16},
+        {"n": 4096, "block_v": 1024},
+        {"n": 2500, "block_v": 1024},
     ), _build_bucket_peel),
     KernelEntry("prefix_positions", (
-        {"n": 64, "block": 16},
-        {"n": 40, "block": 16},
+        {"n": 4096, "block": 1024},                        # 4 steps
+        {"n": 2500, "block": 1024},                        # padded
     ), _build_prefix_positions),
     # frontier_compact / sparse_expand delegate every pallas_call to the
     # prefix_positions scan; capturing through them proves the boundary-
     # marker ownership path builds exactly those sequential scans.
     KernelEntry("frontier_compact", (
-        {"n": 64, "cap": 32, "block": 16},
+        {"n": 4096, "cap": 32, "block": 1024},
     ), _build_frontier_compact),
     KernelEntry("sparse_expand", (
-        {"n": 32, "m": 64, "c": 16, "ecap": 64, "block": 16},
+        {"n": 32, "m": 64, "c": 16, "ecap": 4096, "block": 1024},
     ), _build_sparse_expand),
 )
 

@@ -45,8 +45,11 @@ def shard_map_compat(body, mesh, in_specs: int, out_specs: int, axis):
                       out_specs=(P(axis),) * out_specs)
 
 
-def build_partition(graph: CSRGraph, num_parts: int):
-    """Host-side contiguous row partition of a CSR graph.
+def build_partition(graph: CSRGraph, num_parts: int, sharding):
+    """Host-side contiguous row partition of a CSR graph, placed once on
+    the mesh: row ``d`` of each operand lands on device ``d`` of
+    ``sharding`` (a ``NamedSharding(mesh, P(axis))``), so no device ever
+    holds the whole partition.
 
     Returns (local_indptr (P, nl+1), local_indices (P, ml_max), n_pad).
     ``local_indices`` keeps GLOBAL vertex ids (the status vector is global);
@@ -76,7 +79,8 @@ def build_partition(graph: CSRGraph, num_parts: int):
     local_indices = np.zeros((num_parts, ml_max), np.int32)
     for d, (_, lix) in enumerate(parts):
         local_indices[d, : len(lix)] = lix
-    return (jnp.asarray(local_indptr), jnp.asarray(local_indices), n_pad)
+    return (jax.device_put(local_indptr, sharding),
+            jax.device_put(local_indices, sharding), n_pad)
 
 
 def _axis_size(mesh, axis):
@@ -274,21 +278,24 @@ def _ac3_body(axis, instrument: bool = False, max_rounds: int = 0):
     return run
 
 
-def build_ac4_sharded(graph: CSRGraph, num: int, axis,
-                      instrument: bool = False, max_rounds: int = 0):
-    """AC-4's sharded state: Gᵀ partition + out-degree counters, built once.
+def build_ac4_operands(graph: CSRGraph, num: int, sharding):
+    """AC-4's sharded state: Gᵀ partition + out-degree counters, built once
+    and placed on the mesh with ``sharding`` (see :func:`build_partition`).
 
-    Returns ``(operands, n_pad, body)`` where ``operands`` are the three
-    (P, ...) sharded arrays the body consumes.  The engine caches all of it.
+    Returns ``(operands, n_pad)``: the three (P, ...) sharded arrays
+    :func:`_ac4_body` consumes.  The engine caches them.
     """
     gt = graph.transpose()
-    ltip, ltix, n_pad = build_partition(gt, num)
+    ltip, ltix, n_pad = build_partition(gt, num, sharding)
     nl = n_pad // num
     # deg_out of owned vertices, padded, shaped (P, nl)
     deg_out = np.zeros(n_pad, np.int32)
     deg_out[: graph.n] = np.asarray(graph.out_degrees())
-    deg_out = jnp.asarray(deg_out.reshape(num, nl))
+    deg_out = jax.device_put(deg_out.reshape(num, nl), sharding)
+    return (ltip, ltix, deg_out), n_pad
 
+
+def _ac4_body(axis, instrument: bool = False, max_rounds: int = 0):
     def run(ltip, ltix, deg_out_l):
         ltip, ltix, deg_out_l = ltip[0], ltix[0], deg_out_l[0]
         nl = ltip.shape[0] - 1
@@ -349,8 +356,7 @@ def build_ac4_sharded(graph: CSRGraph, num: int, axis,
             res += (out["stats"]["r_frontier"][None],
                     out["stats"]["r_edges"][None])
         return res
-
-    return (ltip, ltix, deg_out), n_pad, run
+    return run
 
 
 def trim_distributed(graph: CSRGraph, method: str = "ac6",

@@ -453,30 +453,32 @@ class TrimEngine(EngineBase):
         if self._shard is not None:
             return self._shard
         import jax
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
 
+        from ..jaxcompat import make_mesh
         from . import distributed as dist
         mesh, axis = self.mesh, self.axis
         if mesh is None:
-            mesh = jax.make_mesh((len(jax.devices()),), ("workers",))
+            mesh = make_mesh((len(jax.devices()),), ("workers",))
             axis = "workers"
         num = dist._axis_size(mesh, axis)
+        sharding = NamedSharding(mesh, P(axis))
         kind = self.spec.sharded_method
         if kind == "ac4":
-            operands, n_pad, body = dist.build_ac4_sharded(
-                self.graph, num, axis, instrument=self.instrument,
-                max_rounds=self.max_rounds)
-            nspecs = 3
+            operands, n_pad = dist.build_ac4_operands(self.graph, num,
+                                                      sharding)
         else:
-            lip, lix, n_pad = dist.build_partition(self.graph, num)
+            lip, lix, n_pad = dist.build_partition(self.graph, num, sharding)
             operands = (lip, lix)
-            maker = (dist._ac6_body_packed if kind == "ac6" and self.packed
-                     else {"ac3": dist._ac3_body,
-                           "ac6": dist._ac6_body}[kind])
-            body = maker(axis, instrument=self.instrument,
-                         max_rounds=self.max_rounds)
-            nspecs = 3  # (lip, lix, act)
+        maker = (dist._ac6_body_packed if kind == "ac6" and self.packed
+                 else {"ac3": dist._ac3_body, "ac4": dist._ac4_body,
+                       "ac6": dist._ac6_body}[kind])
+        body = maker(axis, instrument=self.instrument,
+                     max_rounds=self.max_rounds)
+        # three operands: (lip, lix, act), or AC-4's (ltip, ltix, deg_out)
         smapped = dist.shard_map_compat(
-            body, mesh, in_specs=nspecs,
+            body, mesh, in_specs=3,
             out_specs=6 if self.instrument else 4, axis=axis)
 
         def call(*arrs):
@@ -484,10 +486,11 @@ class TrimEngine(EngineBase):
             return smapped(*arrs)
 
         self._shard = dict(fn=jax.jit(call), num=num, n_pad=n_pad,
-                           operands=operands, kind=kind)
+                           operands=operands, kind=kind, sharding=sharding)
         return self._shard
 
     def _run_sharded(self, active, counters):
+        import jax
         import jax.numpy as jnp
         sh = self._ensure_sharded()
         n = self.graph.n
@@ -500,7 +503,8 @@ class TrimEngine(EngineBase):
             act = np.zeros(n_pad, bool)
             act[:n] = (True if active is None
                        else np.asarray(active, bool))
-            args = (*sh["operands"], jnp.asarray(act.reshape(num, -1)))
+            args = (*sh["operands"],
+                    jax.device_put(act.reshape(num, -1), sh["sharding"]))
         out = self._dispatch(sh["fn"], *args)
         status_l, edges, rounds, max_qp = out[:4]
         status = status_l.reshape(-1)[:n].astype(jnp.int32)

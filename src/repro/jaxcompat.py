@@ -1,34 +1,25 @@
-"""Version portability for the handful of jax APIs this repo uses that
-moved between releases.  Import from here, not from jax directly:
+"""The few jax APIs this repo wraps, written for the one pinned jax
+release (see ``requirements.txt``).  Import from here, not from jax
+directly:
 
-* ``shard_map`` — ``jax.shard_map`` (jax >= 0.6, vma-typed) or
-  ``jax.experimental.shard_map.shard_map`` with ``check_rep=False`` (older
-  releases choke on while_loop replication rules otherwise).
-* ``mark_varying`` — casts loop carries to device-varying under the new
-  vma type system (``jax.lax.pcast``); identity on releases without it.
-* ``make_mesh`` — forwards ``axis_types`` only where ``jax.sharding``
-  knows about them.
+* ``shard_map`` — ``jax.shard_map`` (vma-typed).
+* ``mark_varying`` — casts loop carries to device-varying under the vma
+  type system (``jax.lax.pcast``), so ``while_loop`` carries built from
+  replicated constants type-check inside ``shard_map``.
+* ``make_mesh`` — ``jax.make_mesh`` with Auto axis types:
+  plain ``jax.make_mesh`` builds Explicit axes, under which ordinary
+  indexing of a sharded result (``x[:n]``) raises ``ShardingTypeError``.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 
-try:
-    shard_map = jax.shard_map
-    HAS_VMA = hasattr(jax.lax, "pcast")
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _esm
-    shard_map = partial(_esm, check_rep=False)
-    HAS_VMA = False
+shard_map = jax.shard_map
 
 
 def mark_varying(tree, axis):
-    """Mark loop carries as device-varying (shard_map vma typing).
-    No-op on jax releases without vma types."""
-    if not HAS_VMA:
-        return tree
+    """Mark loop carries as device-varying along ``axis`` (shard_map vma
+    typing); leaves that already vary are passed through."""
     names = (axis,) if isinstance(axis, str) else tuple(axis)
 
     def cast(x):
@@ -39,10 +30,7 @@ def mark_varying(tree, axis):
     return jax.tree.map(cast, tree)
 
 
-def make_mesh(shape, axes, *, auto: bool = True):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    if auto and hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -24,7 +24,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_V = 512
+from .tiling import vertex_block
+
+DEFAULT_BLOCK_V = 1024
 
 
 def _bucket_kernel(counters_ref, alive_ref, k_ref, frontier_ref):
@@ -53,7 +55,7 @@ def bucket_peel_pallas(counters, alive, k, block_v: int = DEFAULT_BLOCK_V,
     if n == 0:
         return jnp.zeros((0,), jnp.bool_)
     k = jnp.asarray(k, jnp.int32).reshape(1)
-    block_v = min(block_v, n)
+    block_v = vertex_block(block_v, n)
     n_pad = -(-n // block_v) * block_v
     if n_pad != n:
         counters = jnp.pad(counters, (0, n_pad - n))

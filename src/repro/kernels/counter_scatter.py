@@ -16,7 +16,8 @@ integer masked sum (counters are int32-exact), not the MXU — with
 their counters verbatim (``@pl.when``), so a small delta batch costs one
 pass over the counter array and nothing else.
 
-Layout: lanes = vertices within a block (×128), update batch on sublanes.
+Layout: lanes = vertices within a block (×128), update batch on sublanes
+(a (B, 1) column per operand).
 Out-of-range sources (the engine's pow2-padding sentinel ``src = n``) fall
 in no vertex block and contribute nothing.
 """
@@ -28,7 +29,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_V = 512
+from .tiling import vertex_block
+
+DEFAULT_BLOCK_V = 1024
 DEFAULT_BLOCK_U = 256
 
 
@@ -42,16 +45,15 @@ def _counter_kernel(counters_ref, status_ref, src_ref, delta_ref,
     def _seed():
         out_ref[...] = counters_ref[...]
 
-    src = src_ref[...]                               # (block_u,)
-    delta = delta_ref[...]
+    src = src_ref[...]                               # (block_u, 1) int32
     local = src - vi * block_v
-    hit = (local[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (src.shape[0], block_v), 1))      # (block_u, block_v)
+    hit = local == jax.lax.broadcasted_iota(
+        jnp.int32, (src.shape[0], block_v), 1)       # (block_u, block_v)
+    contrib = jnp.where(hit, delta_ref[...], 0)      # int32, lane-broadcast
 
-    @pl.when(jnp.any(hit & (delta != 0)[:, None]))
+    @pl.when(jnp.any(contrib != 0))
     def _accumulate():
-        out_ref[...] += jnp.sum(
-            jnp.where(hit, delta[:, None], 0), axis=0).astype(out_ref.dtype)
+        out_ref[...] += jnp.sum(contrib, axis=0).astype(out_ref.dtype)
 
     @pl.when(ui == nu - 1)
     def _deaths():
@@ -71,6 +73,11 @@ def counter_scatter_pallas(counters, status, upd_src, upd_delta,
     upd_delta:(B,) int32 — counter adjustment per update (+1 insert of a
               live arc, -1 delete, 0 no-op).
 
+    The update batch enters as (B, 1) int32 columns, so the hit matrix is
+    built by broadcasting along lanes — no vector reshape inside the
+    kernel.  ``block_v`` is rounded up to whole vertex tiles
+    (``kernels.tiling``) and ``block_u`` to whole 8-row sublane groups.
+
     Returns ``(new_counters, newly_dead)``: (n,) int32 and (n,) bool.
     """
     n = counters.shape[0]
@@ -79,13 +86,15 @@ def counter_scatter_pallas(counters, status, upd_src, upd_delta,
         return counters, jnp.zeros((0,), jnp.bool_)
     if b == 0:
         return counters, status & (counters <= 0)
-    block_v = min(block_v, n)
-    block_u = min(block_u, b)
+    block_v = vertex_block(block_v, n)
+    block_u = min(-(-max(block_u, 1) // 8) * 8, b)
     n_pad = -(-n // block_v) * block_v
     b_pad = -(-b // block_u) * block_u
     if n_pad != n:
         counters = jnp.pad(counters, (0, n_pad - n))
         status = jnp.pad(status, (0, n_pad - n))
+    upd_src = upd_src.astype(jnp.int32)
+    upd_delta = upd_delta.astype(jnp.int32)
     if b_pad != b:
         # pad sources beyond every vertex block so they never hit
         upd_src = jnp.pad(upd_src, (0, b_pad - b), constant_values=n_pad)
@@ -97,8 +106,8 @@ def counter_scatter_pallas(counters, status, upd_src, upd_delta,
         in_specs=[
             pl.BlockSpec((block_v,), lambda vi, ui: (vi,)),
             pl.BlockSpec((block_v,), lambda vi, ui: (vi,)),
-            pl.BlockSpec((block_u,), lambda vi, ui: (ui,)),
-            pl.BlockSpec((block_u,), lambda vi, ui: (ui,)),
+            pl.BlockSpec((block_u, 1), lambda vi, ui: (ui, 0)),
+            pl.BlockSpec((block_u, 1), lambda vi, ui: (ui, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_v,), lambda vi, ui: (vi,)),
@@ -109,6 +118,5 @@ def counter_scatter_pallas(counters, status, upd_src, upd_delta,
             jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
         ],
         interpret=interpret,
-    )(counters, status, upd_src.astype(jnp.int32),
-      upd_delta.astype(jnp.int32))
+    )(counters, status, upd_src[:, None], upd_delta[:, None])
     return out[:n], dead[:n]

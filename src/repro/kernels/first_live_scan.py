@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_V = 256
+from .tiling import vertex_block
+
+DEFAULT_BLOCK_V = 1024
 
 
 def _scan_kernel(flags_ref, valid_ref, active_ref, first_ref, found_ref,
@@ -53,7 +55,7 @@ def first_live_scan(flags, valid, active, block_v: int = DEFAULT_BLOCK_V,
     (W when none), found (n,) bool.
     """
     n, window = flags.shape
-    block_v = min(block_v, n)
+    block_v = vertex_block(block_v, n)
     n_pad = -(-n // block_v) * block_v
     if n_pad != n:
         pad = n_pad - n

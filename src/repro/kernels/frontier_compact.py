@@ -41,21 +41,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK = 512
+LANES = 128
+TILE = 8 * LANES            # one (8, 128) int32 vreg tile
+DEFAULT_BLOCK = 8 * TILE    # elements per sequential grid step
 
 
 def _scan_kernel(x_ref, out_ref, carry_ref):
-    """One grid step of the sequential exclusive scan: emit the running
-    prefix for this block and push the block total into the SMEM carry."""
+    """One grid step of the sequential exclusive scan over a (rows, 128)
+    block in row-major order: emit the running prefix for this block and
+    push the block total into the SMEM carry.
+
+    Mosaic has no cumsum, so the block scan is two Hillis–Steele passes
+    of shift-and-add (``pltpu.roll`` + lane/sublane masks, int32-exact):
+    log2(128) steps along the lanes of each row, then log2(rows) steps
+    over the row totals down the sublanes."""
     @pl.when(pl.program_id(0) == 0)
     def _init():
         carry_ref[0] = 0
 
-    x = x_ref[...]
+    x = x_ref[...]                                   # (rows, 128) int32
+    rows = x.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    incl = x                                         # within-row inclusive
+    s = 1
+    while s < LANES:
+        incl = incl + jnp.where(lane >= s, pltpu.roll(incl, s, 1), 0)
+        s *= 2
+    tot = jnp.broadcast_to(jnp.sum(x, axis=1, keepdims=True), x.shape)
+    above = tot                                      # inclusive over rows
+    s = 1
+    while s < rows:
+        above = above + jnp.where(row >= s, pltpu.roll(above, s, 0), 0)
+        s *= 2
     base = carry_ref[0]
-    csum = jnp.cumsum(x)
-    out_ref[...] = base + csum - x          # exclusive positions
-    carry_ref[0] = base + csum[-1]
+    out_ref[...] = base + (above - tot) + (incl - x)  # exclusive positions
+    carry_ref[0] = base + jnp.sum(x)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -63,25 +84,31 @@ def prefix_positions(x, block: int = DEFAULT_BLOCK, interpret: bool = True):
     """Exclusive prefix sum of an (n,) int32 vector as a sequential-grid
     Pallas scan (SMEM scalar carry between blocks).  Returns
     ``(positions, total)`` with ``positions[i] = sum(x[:i])`` and
-    ``total = sum(x)``."""
+    ``total = sum(x)``.
+
+    The vector is laid out as (rows, 128) — the TPU's native int32 tile —
+    padded to whole (8, 128) tiles; ``block`` (elements per grid step) is
+    rounded up to whole tiles the same way."""
     n = x.shape[0]
     if n == 0:
         return jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32)
     x = x.astype(jnp.int32)
-    block = min(block, n)
-    n_pad = -(-n // block) * block
+    n_tiles = -(-n // TILE)
+    block_rows = 8 * min(max(-(-block // TILE), 1), n_tiles)
+    rows = -(-(n_tiles * 8) // block_rows) * block_rows
+    n_pad = rows * LANES
     if n_pad != n:
         x = jnp.pad(x, (0, n_pad - n))
 
     pos = pl.pallas_call(
         _scan_kernel,
-        grid=(n_pad // block,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32),
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
-    )(x)
+    )(x.reshape(rows, LANES)).reshape(n_pad)
     total = pos[n - 1] + x[n - 1]
     return pos[:n], total
 

@@ -23,7 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_V = 256
+from .tiling import vertex_block
+
+DEFAULT_BLOCK_V = 1024
 
 
 def _expand_kernel(flags_ref, valid_ref, pending_ref, hit_ref):
@@ -53,7 +55,7 @@ def frontier_expand(flags, valid, pending, block_v: int = DEFAULT_BLOCK_V,
     n, window = flags.shape
     if n == 0:
         return jnp.zeros((0,), jnp.bool_)
-    block_v = min(block_v, n)
+    block_v = vertex_block(block_v, n)
     n_pad = -(-n // block_v) * block_v
     if n_pad != n:
         pad = n_pad - n

@@ -1,15 +1,15 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True when no TPU is attached (this container is
-CPU-only; TPU v5e is the lowering TARGET).  Model code calls these wrappers,
-never pallas_call directly; the dry-run lowers with ``interpret=False``
-disabled paths replaced by the jnp references so HLO stays analyzable.
+``use_kernel=None`` picks the Pallas kernel when a TPU is attached and the
+jnp reference twin (``kernels.ref``) otherwise.  A kernel forced on
+without a TPU runs in the Pallas interpreter; on a TPU it is always
+compiled.  Engine code calls these wrappers, never pallas_call directly.
 
-Every wrapper notes its kernel choice to the span recorder via
-:func:`repro.obs.note_kernel`.  Inside a jitted caller that Python runs
-at *trace* time only, so each note marks a kernel selection being baked
-into a fresh executable — retrace attribution for free, and a no-op
-(one attribute read) when no recorder is installed.
+Every wrapper notes its kernel choice (``use_kernel`` and ``interpret``)
+to the span recorder via :func:`repro.obs.note_kernel`.  Inside a jitted
+caller that Python runs at *trace* time only, so each note marks a kernel
+selection being baked into a fresh executable — retrace attribution for
+free, and a no-op (one attribute read) when no recorder is installed.
 """
 from __future__ import annotations
 
@@ -44,16 +44,27 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _select(kernel: str, use_kernel: bool | None) -> tuple[bool, bool]:
+    """Resolve ``use_kernel=None`` (the Pallas kernel iff a TPU is
+    attached), note the choice to the span recorder, and return
+    ``(use_kernel, interpret)``: off-TPU a forced kernel runs in the
+    Pallas interpreter, on a TPU it is compiled by Mosaic."""
+    if use_kernel is None:
+        use_kernel = on_tpu()
+    use_kernel = bool(use_kernel)
+    interpret = use_kernel and not on_tpu()
+    obs.note_kernel(kernel, use_kernel=use_kernel, interpret=interpret)
+    return use_kernel, interpret
+
+
 def flash_attention(q, k, v, *, causal=True, sm_scale=None,
                     use_kernel: bool | None = None, **kw):
     """use_kernel=None: Pallas kernel on TPU; off-TPU the chunked jnp flash
     twin (same math, streaming memory) so lowering/dry-run stays sane."""
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    obs.note_kernel("flash_attention", use_kernel=bool(use_kernel))
+    use_kernel, interpret = _select("flash_attention", use_kernel)
     if use_kernel:
         return _fa(q, k, v, causal=causal, sm_scale=sm_scale,
-                   interpret=not on_tpu(), **kw)
+                   interpret=interpret, **kw)
     try:
         from ..launch.perf_flags import FLAGS
     except ImportError as e:
@@ -75,32 +86,25 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None,
 
 def segment_sum(values, seg_ids, num_segments: int,
                 use_kernel: bool | None = None, **kw):
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    obs.note_kernel("segment_sum", use_kernel=bool(use_kernel))
+    use_kernel, interpret = _select("segment_sum", use_kernel)
     if use_kernel:
-        return _ssp(values, seg_ids, num_segments,
-                    interpret=not on_tpu(), **kw)
+        return _ssp(values, seg_ids, num_segments, interpret=interpret, **kw)
     return ref.segment_sum_ref(values, seg_ids, num_segments)
 
 
 def first_live_scan(flags, valid, active, use_kernel: bool | None = None,
                     **kw):
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    obs.note_kernel("first_live_scan", use_kernel=bool(use_kernel))
+    use_kernel, interpret = _select("first_live_scan", use_kernel)
     if use_kernel:
-        return _fls(flags, valid, active, interpret=not on_tpu(), **kw)
+        return _fls(flags, valid, active, interpret=interpret, **kw)
     return ref.first_live_ref(flags, valid, active)
 
 
 def frontier_expand(flags, valid, pending, use_kernel: bool | None = None,
                     **kw):
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    obs.note_kernel("frontier_expand", use_kernel=bool(use_kernel))
+    use_kernel, interpret = _select("frontier_expand", use_kernel)
     if use_kernel:
-        return _fex(flags, valid, pending, interpret=not on_tpu(), **kw)
+        return _fex(flags, valid, pending, interpret=interpret, **kw)
     return ref.frontier_expand_ref(flags, valid, pending)
 
 
@@ -108,11 +112,9 @@ def frontier_compact(mask, capacity: int, use_kernel: bool | None = None,
                      **kw):
     """(n,) bool -> (ids, count): frontier members compacted into a
     static (capacity,) int32 buffer (sentinel n) + the member count."""
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    obs.note_kernel("frontier_compact", use_kernel=bool(use_kernel))
+    use_kernel, interpret = _select("frontier_compact", use_kernel)
     if use_kernel:
-        return _fcp(mask, capacity, interpret=not on_tpu(), **kw)
+        return _fcp(mask, capacity, interpret=interpret, **kw)
     return ref.frontier_compact_ref(mask, capacity)
 
 
@@ -120,29 +122,23 @@ def sparse_expand(indptr, indices, ids, ecap: int,
                   use_kernel: bool | None = None, **kw):
     """CSR rows of compacted ``ids`` expanded into a static (ecap,) edge
     buffer: ``(src, tgt, pos, valid)`` per slot."""
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    obs.note_kernel("sparse_expand", use_kernel=bool(use_kernel))
+    use_kernel, interpret = _select("sparse_expand", use_kernel)
     if use_kernel:
-        return _sxp(indptr, indices, ids, ecap, interpret=not on_tpu(), **kw)
+        return _sxp(indptr, indices, ids, ecap, interpret=interpret, **kw)
     return ref.sparse_expand_ref(indptr, indices, ids, ecap)
 
 
 def counter_scatter(counters, status, upd_src, upd_delta,
                     use_kernel: bool | None = None, **kw):
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    obs.note_kernel("counter_scatter", use_kernel=bool(use_kernel))
+    use_kernel, interpret = _select("counter_scatter", use_kernel)
     if use_kernel:
         return _csc(counters, status, upd_src, upd_delta,
-                    interpret=not on_tpu(), **kw)
+                    interpret=interpret, **kw)
     return ref.counter_scatter_ref(counters, status, upd_src, upd_delta)
 
 
 def bucket_peel(counters, alive, k, use_kernel: bool | None = None, **kw):
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    obs.note_kernel("bucket_peel", use_kernel=bool(use_kernel))
+    use_kernel, interpret = _select("bucket_peel", use_kernel)
     if use_kernel:
-        return _bpl(counters, alive, k, interpret=not on_tpu(), **kw)
+        return _bpl(counters, alive, k, interpret=interpret, **kw)
     return ref.bucket_peel_ref(counters, alive, k)

@@ -7,7 +7,8 @@ links).  Hardware constants used by the roofline layer live here too.
 """
 from __future__ import annotations
 
-# TPU v5e per-chip peaks (assignment-provided)
+# TPU v5e per-chip peaks, from Google Cloud's "TPU v5e" documentation
+# (bf16 peak, HBM bandwidth; ICI_BW is 1,600 Gbit/s over 4 links)
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
 HBM_BW = 819e9                    # bytes/s
 ICI_BW = 50e9                     # bytes/s per link
@@ -17,7 +18,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     from ..jaxcompat import make_mesh
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, auto=True)
+    return make_mesh(shape, axes)
 
 
 def data_axes(multi_pod: bool) -> tuple[str, ...]:

@@ -480,6 +480,8 @@ def main():
     ap.add_argument("--retries", type=int, default=3,
                     help="bound on consecutive recovery attempts per tick")
     args = ap.parse_args()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.app == "trim-stream":
         serve_trim_stream(args.graph, ticks=args.ticks,
                           batch=args.update_batch,

@@ -297,7 +297,9 @@ def main():
     import contextlib
 
     from .. import obs
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.fault_seed is not None:
         from .. import fault as flt
         fault_scope = flt.injecting_faults(

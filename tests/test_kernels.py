@@ -11,6 +11,7 @@ from repro.kernels.counter_scatter import counter_scatter_pallas
 from repro.kernels.first_live_scan import first_live_scan
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.frontier_compact import (frontier_compact_pallas,
+                                            prefix_positions,
                                             sparse_expand_pallas)
 from repro.kernels.frontier_expand import frontier_expand
 from repro.kernels.segment_reduce import segment_sum_pallas
@@ -64,7 +65,7 @@ def test_segment_sum(m, d, n, be, bn):
 
 
 @pytest.mark.parametrize("n,W,bv", [(333, 16, 128), (64, 8, 64),
-                                    (1024, 32, 256)])
+                                    (1024, 32, 256), (3000, 16, 1024)])
 def test_first_live_scan(n, W, bv):
     flags = jnp.asarray(RNG.random((n, W)) < 0.3)
     valid = jnp.asarray(RNG.random((n, W)) < 0.8)
@@ -81,6 +82,7 @@ def test_first_live_scan(n, W, bv):
     (1024, 256, 256, 64),
     (7, 3, 512, 256),      # smaller than one block
     (50, 1, 512, 256),     # single update
+    (3000, 300, 1024, 64),  # 3 x 5 grid, both axes padded
 ])
 def test_counter_scatter(n, b, bv, bu):
     counters = jnp.asarray(RNG.integers(0, 5, n), jnp.int32)
@@ -137,7 +139,7 @@ def test_counter_scatter_duplicate_sources(n, b, bv, bu):
 
 
 @pytest.mark.parametrize("n,bv", [(333, 128), (64, 64), (1024, 256),
-                                  (7, 512), (513, 512)])
+                                  (7, 512), (513, 512), (2500, 1024)])
 def test_bucket_peel(n, bv):
     counters = jnp.asarray(RNG.integers(-2, 8, n), jnp.int32)
     alive = jnp.asarray(RNG.random(n) < 0.6)
@@ -164,7 +166,8 @@ def test_bucket_peel_empty():
 
 
 @pytest.mark.parametrize("n,W,bv", [(333, 16, 128), (64, 8, 64),
-                                    (1024, 32, 256), (7, 4, 256)])
+                                    (1024, 32, 256), (7, 4, 256),
+                                    (3000, 16, 1024)])
 def test_frontier_expand(n, W, bv):
     flags = jnp.asarray(RNG.random((n, W)) < 0.2)
     valid = jnp.asarray(RNG.random((n, W)) < 0.8)
@@ -192,7 +195,7 @@ def _compact_oracle(mask, capacity):
 
 @pytest.mark.parametrize("n,cap,block", [(0, 8, 512), (1, 1, 512),
                                          (333, 64, 64), (1024, 1024, 512),
-                                         (700, 16, 128)])
+                                         (700, 16, 128), (5000, 4096, 1024)])
 @pytest.mark.parametrize("fill", ["none", "some", "all"])
 def test_frontier_compact(n, cap, block, fill):
     """Pallas scan vs jnp ref vs numpy oracle — including the all-dead
@@ -211,7 +214,8 @@ def test_frontier_compact(n, cap, block, fill):
 
 @pytest.mark.parametrize("n,m,cap,ecap", [(0, 0, 8, 16), (5, 0, 8, 16),
                                           (64, 256, 16, 512),
-                                          (333, 1000, 64, 2048)])
+                                          (333, 1000, 64, 2048),
+                                          (2000, 12000, 512, 16384)])
 def test_sparse_expand(n, m, cap, ecap):
     """Expansion of compacted CSR rows vs a numpy oracle, zero-degree rows
     and the degenerate n=0/m=0 shapes included."""
@@ -242,6 +246,30 @@ def test_sparse_expand(n, m, cap, ecap):
                               np.asarray(w_tgt)[valid[:total]])
         assert np.array_equal(p[:total][valid[:total]],
                               np.asarray(w_pos)[valid[:total]])
+
+
+@pytest.mark.parametrize("n,block", [(1, 1024), (1023, 1024),
+                                     (5000, 1024), (20000, 8192),
+                                     (9000, 100)])
+def test_prefix_positions(n, block):
+    """The shift-and-add block scan + SMEM carry vs numpy, across single,
+    multi-block and padded grids (a block request below one (8, 128) tile
+    rounds up to one), with prefixes far past 2**24, where a float32
+    accumulation would lose int32 exactness (totals stay below 2**31)."""
+    x = RNG.integers(0, 1 << 16, n).astype(np.int32)
+    pos, total = prefix_positions(jnp.asarray(x), block=block,
+                                  interpret=True)
+    want = np.concatenate([[0], np.cumsum(x, dtype=np.int64)[:-1]])
+    assert np.array_equal(np.asarray(pos), want)
+    assert int(total) == int(x.sum(dtype=np.int64))
+
+
+def test_vertex_block_rounds_to_tiles():
+    from repro.kernels.tiling import VERTEX_TILE, vertex_block
+    assert vertex_block(256, 1 << 22) == VERTEX_TILE
+    assert vertex_block(1500, 1 << 22) == 2 * VERTEX_TILE
+    assert vertex_block(4096, 1 << 22) == 4096
+    assert vertex_block(1024, 700) == 700          # one block = whole array
 
 
 def test_frontier_compact_no_retrace():
