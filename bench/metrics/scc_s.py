@@ -1,0 +1,7 @@
+"""Seconds per SCC decomposition: the window's wall time over the calls
+completed in it (a call in flight at the window's end finishes and
+counts)."""
+
+
+def read(ctx):
+    return ctx.window_s / max(ctx.calls, 1)
