@@ -48,7 +48,7 @@ from . import ac3 as _ac3  # noqa: F401  (imports register the kernels)
 from . import ac4 as _ac4  # noqa: F401
 from . import ac6 as _ac6  # noqa: F401
 from .common import FrontierPlan, frontier_plan
-from .enginebase import _TRACE_COUNT, EngineBase
+from .enginebase import _TRACE_COUNT, EngineBase, jit_named
 from .graph import CSRGraph, TrimResult, row_ids, worker_of
 from .registry import available_methods, get_kernel
 
@@ -71,8 +71,6 @@ def _local_runner(method: str, probe: str, window: int,
     keep their own cache entries, so turning instrumentation on elsewhere
     never retraces them.
     """
-    import jax
-
     spec = get_kernel(method)
 
     def call(indptr, indices, tarrs, worker_ids, active):
@@ -83,10 +81,8 @@ def _local_runner(method: str, probe: str, window: int,
                         frontier=fplan, instrument=instrument,
                         max_rounds=max_rounds)
 
-    fn = call
-    if batched:
-        fn = jax.vmap(call, in_axes=(None, None, None, None, 0))
-    return jax.jit(fn)
+    return jit_named(call, f"trim_{method}",
+                     (None, None, None, None, 0) if batched else None)
 
 
 def plan(graph: CSRGraph, method: str = "ac6", backend: str = "dense", *,
@@ -485,7 +481,8 @@ class TrimEngine(EngineBase):
             _TRACE_COUNT[0] += 1
             return smapped(*arrs)
 
-        self._shard = dict(fn=jax.jit(call), num=num, n_pad=n_pad,
+        name = f"trim_{kind}{'_packed' if self.packed else ''}_sharded"
+        self._shard = dict(fn=jit_named(call, name), num=num, n_pad=n_pad,
                            operands=operands, kind=kind, sharding=sharding)
         return self._shard
 

@@ -25,9 +25,10 @@ device-resident results.  This module holds the plumbing they share:
 Every dispatch is additionally wrapped in an ``obs`` span (DESIGN.md
 §11): engine family, plan signature, wall time, and compile-vs-execute
 attribution (``phase="compile+execute"`` when the dispatch caused one or
-more kernel traces).  The global recorder is disabled by default, in
-which case the span context is a no-op — un-observed runs pay a single
-attribute read per dispatch.
+more kernel traces).  The span is a profiler annotation named
+``engine.dispatch``; the global recorder is disabled by default, in
+which case it records nothing — un-observed runs pay an inactive
+``TraceMe`` per dispatch.
 
 When the process-global MetricsPlane is enabled (DESIGN.md §13) each
 dispatch additionally feeds the continuous layer: a per-family latency
@@ -53,6 +54,18 @@ from .graph import CSRGraph
 # i.e. exactly once per compilation).  Engines attribute deltas to
 # themselves around each dispatch; tests assert on it (DESIGN.md §7).
 _TRACE_COUNT = [0]
+
+
+def jit_named(fn, name: str, in_axes=None):
+    """``jax.jit`` of ``fn`` compiled as the program ``jit_<name>``, or,
+    vmapped over ``in_axes``, as ``jit_<name>_batch``: the module names a
+    profiler trace's ``XLA Modules`` line shows (``jit_trim_ac6``,
+    ``jit_reach_pull_batch``).  Only the name changes, not the program."""
+    import jax
+    if in_axes is not None:
+        name += "_batch"
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn if in_axes is None else jax.vmap(fn, in_axes=in_axes))
 
 
 class EngineBase:
@@ -120,7 +133,7 @@ class EngineBase:
 
     def _dispatch(self, fn, *args):
         """Call a jitted runner, attributing trace deltas and counting the
-        dispatch.  Each dispatch is one ``obs`` span (no-op context when
+        dispatch.  Each dispatch is one ``obs`` span (no record when
         the global recorder is disabled) and, when the MetricsPlane is
         enabled, one latency-histogram sample plus counter updates.
 
@@ -282,4 +295,4 @@ class EngineBase:
             ).set(float(np.max(rs.imbalance())), family=self.family)
 
 
-__all__ = ["EngineBase", "_TRACE_COUNT"]
+__all__ = ["EngineBase", "_TRACE_COUNT", "jit_named"]

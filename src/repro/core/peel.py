@@ -52,7 +52,7 @@ import numpy as np
 
 from .. import obs
 from .common import FrontierPlan, frontier_plan
-from .enginebase import _TRACE_COUNT, EngineBase
+from .enginebase import _TRACE_COUNT, EngineBase, jit_named
 from .graph import CSRGraph, row_ids
 from .registry import KernelSpec, get_kernel, register_kernel
 
@@ -204,8 +204,6 @@ def _peel_runner(method: str, k_stop, use_kernel, batched: bool,
     the engine hands the dense plan in when ``batched`` (vmap lowers the
     direction cond to a select that would run both bodies).
     ``instrument``/``max_rounds`` select the stats-carrying variant."""
-    import jax
-
     spec = get_kernel(method, family="peel")
 
     def call(garrs, tarrs, active):
@@ -214,10 +212,8 @@ def _peel_runner(method: str, k_stop, use_kernel, batched: bool,
                         use_kernel=use_kernel, frontier=fplan,
                         instrument=instrument, max_rounds=max_rounds)
 
-    fn = call
-    if batched:
-        fn = jax.vmap(call, in_axes=(None, None, 0))
-    return jax.jit(fn)
+    return jit_named(call, f"peel_{method}",
+                     (None, None, 0) if batched else None)
 
 
 # -- results -------------------------------------------------------------------

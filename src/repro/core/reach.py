@@ -45,7 +45,7 @@ import numpy as np
 
 from .. import obs
 from .common import FrontierPlan, frontier_plan
-from .enginebase import _TRACE_COUNT, EngineBase
+from .enginebase import _TRACE_COUNT, EngineBase, jit_named
 from .graph import CSRGraph, row_ids
 from .registry import KernelSpec, get_kernel, register_kernel
 
@@ -323,8 +323,6 @@ def _reach_runner(method: str, window: int, use_kernel, batched: bool,
     ``instrument``/``max_rounds`` select the stats-carrying variant
     (DESIGN.md §11); un-instrumented plans keep their own cache entries.
     """
-    import jax
-
     spec = get_kernel(method, family="reach")
 
     def call(garrs, tarrs, seeds, active):
@@ -334,10 +332,8 @@ def _reach_runner(method: str, window: int, use_kernel, batched: bool,
                         overflow=overflow, frontier=fplan,
                         instrument=instrument, max_rounds=max_rounds)
 
-    fn = call
-    if batched:
-        fn = jax.vmap(call, in_axes=(None, None, 0, 0))
-    return jax.jit(fn)
+    return jit_named(call, f"reach_{method}",
+                     (None, None, 0, 0) if batched else None)
 
 
 # -- results -------------------------------------------------------------------

@@ -32,8 +32,9 @@ compiled substrate as trimming (``core.reach``, DESIGN.md §8), labels stay
 device-resident until the single materialization at the end, and the host
 only steers (region bookkeeping, pivot picking — O(Bn) mask work).
 
-The four engines (trim FW/BW, reach FW/BW) share one transpose build: the
-backward engines sweep Gᵀ with their own caches pre-seeded with G, and Gᵀ
+The four engines (trim FW/BW, reach FW/BW) share the driver's one
+transpose build: the forward engines are pre-seeded with Gᵀ, the backward
+engines sweep Gᵀ with their own caches pre-seeded with G, and Gᵀ
 has G's exact array shapes, so each kernel is traced once per batch width
 — except when G's max in-degree and max out-degree fall on opposite sides
 of the reach window, where the two directions compile different pull
@@ -44,14 +45,19 @@ dispatch and two batched reach dispatches (asserted against the engines'
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import numpy as np
 
 from .. import obs
 from .engine import plan
+from .enginebase import jit_named
 from .graph import CSRGraph
 from .reach import plan_reach
+
+#: ``stats`` entries that time one call (seconds); never checkpointed
+_CALL_SECONDS = ("plan_s", "transpose_s", "sync_s")
 
 
 def _pad_pow2(masks: np.ndarray) -> np.ndarray:
@@ -106,7 +112,6 @@ def _trim2_runner():
     ``(detected, partner)``: (B, n) bool and (B, n) int32 (partner ==
     index for singletons and undetected rows).
     """
-    import jax
     import jax.numpy as jnp
 
     def rowsum(indptr, per_edge):
@@ -136,7 +141,7 @@ def _trim2_runner():
         partner = jnp.where(pair_out, succ, jnp.where(pair_in, pred, idx))
         return detected, partner.astype(jnp.int32)
 
-    return jax.jit(jax.vmap(detect, in_axes=(None, None, None, None, 0)))
+    return jit_named(detect, "scc_trim2", (None, None, None, None, 0))
 
 
 def scc_decompose(graph: CSRGraph, use_trim: bool = True,
@@ -210,10 +215,21 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
 
     ``instrument=True`` plans all four engines with round-level telemetry
     (DESIGN.md §11): ``stats["trim_rounds"]`` / ``stats["reach_rounds"]``
-    accumulate total fixpoint rounds, and each generation emits an
-    ``obs.span`` (cat ``"scc"``) with its region count when a recorder is
-    active, so one ``obs.recording()`` around the call yields the full
-    per-generation trace.
+    accumulate total fixpoint rounds.
+
+    Every call is traced as ``obs`` spans (cat ``"scc"``), which a
+    profiler trace holds as ``scc.<name>`` annotations and an
+    ``obs.recording()`` around the call as records: ``transpose`` (the
+    host counting sort of Gᵀ and its upload), ``plan`` (the four
+    engines), one ``generation`` per worklist generation (its region
+    count, and its pivots once chosen) and inside it ``trim``, ``trim2``,
+    one ``reach`` per direction (``dir="fw"|"bw"``) and a ``sync`` around
+    each blocking device→host read (the worklist and label-counter
+    blobs, the children masks and the final labels).  The same spans
+    fill three float ``stats`` entries on every call:
+    ``stats["transpose_s"]``, ``stats["plan_s"]`` and ``stats["sync_s"]``,
+    the seconds spent in those spans.  They time this call alone and are
+    not checkpointed.
 
     ``checkpoint_dir`` + ``checkpoint_every=k`` (DESIGN.md §14) save the
     generation-level driver state — labels, the pending region worklist,
@@ -237,7 +253,8 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
                                   if counters else None),
              "trim_rounds": 0 if instrument else None,
              "reach_rounds": 0 if instrument else None,
-             "engine_traces": 0, "transpose_builds": 1}
+             "engine_traces": 0, "transpose_builds": 1,
+             **dict.fromkeys(_CALL_SECONDS, 0.0)}
     if n == 0:
         return np.zeros(0, np.int64), stats
     if trim_backend == "sharded":
@@ -248,33 +265,63 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
         raise ValueError(f"max_batch must be a positive power of two, "
                          f"got {max_batch}")
 
-    # four engines, one transpose build: the backward pair sweeps Gᵀ with
-    # its transpose cache pre-seeded with G itself
-    if use_trim:
-        fw_trim = plan(graph, method=trim_method, backend=trim_backend,
-                       window=window, workers=workers, chunk=chunk,
-                       frontier=frontier, instrument=instrument,
-                       max_rounds=max_rounds)
-        gt = fw_trim.transpose           # the one and only build
-        bw_trim = plan(gt, method=trim_method, backend=trim_backend,
-                       window=window, transpose=graph, workers=workers,
-                       chunk=chunk, frontier=frontier,
-                       instrument=instrument, max_rounds=max_rounds)
-    else:
+    @contextlib.contextmanager
+    def span(name, seconds=None, **attrs):
+        """One ``scc.<name>`` span; ``seconds`` names the ``stats`` entry
+        its duration adds to."""
+        scope = obs.span(name, cat="scc", **attrs)
+        try:
+            with scope as sp:
+                yield sp
+        finally:
+            if seconds is not None:
+                stats[seconds] += scope.seconds
+
+    def sync(x) -> np.ndarray:
+        """A blocking device→host read, timed as ``scc.sync``."""
+        with span("sync", "sync_s"):
+            return np.asarray(x)
+
+    def sweep(reach, direction, seeds, live_host, B):
+        # all B pivots advance together: one vmapped dispatch per
+        # direction (per max_batch chunk)
+        with span("reach", dir=direction):
+            outs = [reach.run_batch(s, a)
+                    for s, a in zip(_chunks(seeds, max_batch),
+                                    _chunks(live_host, max_batch))]
+            mask = jnp.concatenate([o.mask for o in outs])[:B]
+        if instrument:
+            stats["reach_rounds"] += int(sum(sync(o.rounds).sum()
+                                             for o in outs))
+        return mask
+
+    # four engines, one transpose build: the forward engines are
+    # pre-seeded with Gᵀ, the backward pair sweeps Gᵀ with its transpose
+    # cache pre-seeded with G itself
+    with span("transpose", "transpose_s"):
+        gt = graph.transpose()                # the one and only build
+    with span("plan", "plan_s"):
         fw_trim = bw_trim = None
-        gt = graph.transpose()
-    fw_reach = plan_reach(graph, backend=reach_backend, window=window,
-                          transpose=gt, frontier=frontier,
-                          instrument=instrument, max_rounds=max_rounds)
-    bw_reach = plan_reach(gt, backend=reach_backend, window=window,
-                          transpose=graph, frontier=frontier,
-                          instrument=instrument, max_rounds=max_rounds)
-    if trim2:
-        # G and Gᵀ CSR arrays for the size-≤2 detector (device-resident,
-        # shared across every generation); the Gᵀ pair reuses the one
-        # transpose build above
-        t2_arrs = (graph.indptr, graph.indices, gt.indptr, gt.indices)
-        t2_fn = _trim2_runner()
+        if use_trim:
+            fw_trim = plan(graph, method=trim_method, backend=trim_backend,
+                           window=window, transpose=gt, workers=workers,
+                           chunk=chunk, frontier=frontier,
+                           instrument=instrument, max_rounds=max_rounds)
+            bw_trim = plan(gt, method=trim_method, backend=trim_backend,
+                           window=window, transpose=graph, workers=workers,
+                           chunk=chunk, frontier=frontier,
+                           instrument=instrument, max_rounds=max_rounds)
+        fw_reach = plan_reach(graph, backend=reach_backend, window=window,
+                              transpose=gt, frontier=frontier,
+                              instrument=instrument, max_rounds=max_rounds)
+        bw_reach = plan_reach(gt, backend=reach_backend, window=window,
+                              transpose=graph, frontier=frontier,
+                              instrument=instrument, max_rounds=max_rounds)
+        if trim2:
+            # G and Gᵀ CSR arrays for the size-≤2 detector
+            # (device-resident, shared across every generation)
+            t2_arrs = (graph.indptr, graph.indices, gt.indptr, gt.indices)
+            t2_fn = _trim2_runner()
 
     labels = jnp.full((n,), -1, jnp.int32)   # device-resident until the end
     next_label = 0
@@ -297,7 +344,7 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
         if counters:
             tree["per_worker_edges"] = stats["per_worker_edges"]
         drv_stats = {k: v for k, v in stats.items()
-                     if k != "per_worker_edges"}
+                     if k != "per_worker_edges" and k not in _CALL_SECONDS}
         save_tree(checkpoint_dir, gens, tree,
                   {"driver": {"kind": "scc", "next_label": next_label,
                               "stats": drv_stats}},
@@ -328,138 +375,125 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
         n_regions = len(regions)
         live_host = _pad_pow2(np.stack(regions))          # (B, n), disjoint
         regions = []
-        # the span is opened/closed manually: the loop body has early
-        # `continue`s, and a `with` around 100 lines would bury them
-        gen_span = obs.span("generation", cat="scc",
-                            gen=stats["generations"], regions=n_regions)
-        gen_sp = gen_span.__enter__()
+        with span("generation", gen=stats["generations"],
+                  regions=n_regions) as gen_sp:
+            if use_trim:
+                # one batched dispatch (per max_batch chunk) trims every
+                # pending region; directions alternate by generation so
+                # source- and sink-like trivial SCCs both peel without a
+                # second dispatch
+                with span("trim"):
+                    engine = (fw_trim if stats["generations"] % 2 == 1
+                              or not trim_transpose else bw_trim)
+                    parts = [engine.run_batch_stacked(jnp.asarray(c),
+                                                      counters=counters)
+                             for c in _chunks(live_host, max_batch)]
+                    status = jnp.concatenate([p[0] for p in parts]) != 0
+                    live = jnp.asarray(live_host)
+                    dead = live & ~status
+                    live = live & status
+                    # regions are disjoint, so the union keeps one label
+                    # per vertex
+                    dead_union = jnp.any(dead, axis=0)
+                stats["trim_passes"] += n_regions
+                if counters:
+                    # one (B, workers) transfer per generation (int32, the
+                    # kernels' own accumulator width); cross-region and
+                    # cross-worker sums in int64 on the host
+                    pw = sync(jnp.concatenate(
+                        [p[1] for p in parts])[:n_regions]).astype(np.int64)
+                    stats["trim_edges_traversed"] += int(pw.sum())
+                    stats["per_worker_edges"] += pw.sum(axis=0)
+                if instrument:
+                    stats["trim_rounds"] += int(sync(jnp.concatenate(
+                        [p[2] for p in parts])[:n_regions]).sum())
+                # one device->host transfer serves both the label counter
+                # and the worklist bookkeeping below
+                blob = sync(jnp.concatenate([dead_union[None], live]))
+                dead_host, live_host = blob[0], blob[1:]
+                k = int(dead_host.sum())
+                if k:
+                    rank = jnp.cumsum(dead_union.astype(jnp.int32)) - 1
+                    labels = jnp.where(dead_union, next_label + rank, labels)
+                    next_label += k
+                    stats["trimmed_total"] += k
 
-        if use_trim:
-            # one batched dispatch (per max_batch chunk) trims every
-            # pending region; directions alternate by generation so
-            # source- and sink-like trivial SCCs both peel without a
-            # second dispatch
-            engine = (fw_trim if stats["generations"] % 2 == 1
-                      or not trim_transpose else bw_trim)
-            parts = [engine.run_batch_stacked(jnp.asarray(c),
-                                              counters=counters)
-                     for c in _chunks(live_host, max_batch)]
-            stats["trim_passes"] += n_regions
-            if counters:
-                # one (B, workers) transfer per generation (int32, the
-                # kernels' own accumulator width); cross-region and
-                # cross-worker sums in int64 on the host
-                pw = np.asarray(jnp.concatenate(
-                    [p[1] for p in parts])[:n_regions]).astype(np.int64)
-                stats["trim_edges_traversed"] += int(pw.sum())
-                stats["per_worker_edges"] += pw.sum(axis=0)
-            if instrument:
-                stats["trim_rounds"] += int(np.asarray(jnp.concatenate(
-                    [p[2] for p in parts])[:n_regions]).sum())
-            status = jnp.concatenate([p[0] for p in parts]) != 0
-            live = jnp.asarray(live_host)
-            dead = live & ~status
-            live = live & status
-            # regions are disjoint, so the union keeps one label per vertex
-            dead_union = jnp.any(dead, axis=0)
-            # one device->host transfer serves both the label counter and
-            # the worklist bookkeeping below
-            blob = np.asarray(jnp.concatenate([dead_union[None], live]))
-            dead_host, live_host = blob[0], blob[1:]
-            k = int(dead_host.sum())
-            if k:
-                rank = jnp.cumsum(dead_union.astype(jnp.int32)) - 1
-                labels = jnp.where(dead_union, next_label + rank, labels)
-                next_label += k
-                stats["trimmed_total"] += k
+            if trim2 and live_host.any():
+                # one batched dispatch (per max_batch chunk) detects
+                # size-≤2 SCCs across every pending region; each
+                # pair/singleton gets one label keyed by its
+                # representative (min endpoint) and leaves the worklist
+                # before any pivot is spent on it
+                with span("trim2"):
+                    parts2 = [t2_fn(*t2_arrs, jnp.asarray(c))
+                              for c in _chunks(live_host, max_batch)]
+                    stats["trim2_dispatches"] += len(parts2)
+                    det = jnp.concatenate([p[0] for p in parts2])
+                    # regions are disjoint, so the per-vertex
+                    # partner/detected unions keep one value per vertex
+                    partner = jnp.max(
+                        jnp.concatenate([jnp.where(p[0], p[1], -1)
+                                         for p in parts2]), axis=0)
+                    det_union = jnp.any(det, axis=0)
+                    idx = jnp.arange(n, dtype=jnp.int32)
+                    is_rep = det_union & (idx <= partner)
+                    rep = jnp.where(det_union, jnp.minimum(idx, partner),
+                                    idx)
+                    rank2 = jnp.cumsum(is_rep.astype(jnp.int32)) - 1
+                # one device->host transfer serves the label counter, the
+                # removal stat, and the worklist bookkeeping
+                blob2 = sync(jnp.concatenate(
+                    [is_rep[None], det_union[None],
+                     jnp.asarray(live_host) & ~det]))
+                n_sccs = int(blob2[0].sum())
+                if n_sccs:
+                    labels = jnp.where(det_union,
+                                       next_label + rank2[rep], labels)
+                    next_label += n_sccs
+                    stats["trim2_sccs"] += n_sccs
+                    stats["trim2_removed"] += int(blob2[1].sum())
+                    live_host = blob2[2:]
 
-        if trim2 and live_host.any():
-            # one batched dispatch (per max_batch chunk) detects size-≤2
-            # SCCs across every pending region; each pair/singleton gets
-            # one label keyed by its representative (min endpoint) and
-            # leaves the worklist before any pivot is spent on it
-            parts2 = [t2_fn(*t2_arrs, jnp.asarray(c))
-                      for c in _chunks(live_host, max_batch)]
-            stats["trim2_dispatches"] += len(parts2)
-            det = jnp.concatenate([p[0] for p in parts2])
-            # regions are disjoint, so the per-vertex partner/detected
-            # unions keep one value per vertex
-            partner = jnp.max(
-                jnp.concatenate([jnp.where(p[0], p[1], -1)
-                                 for p in parts2]), axis=0)
-            det_union = jnp.any(det, axis=0)
-            idx = jnp.arange(n, dtype=jnp.int32)
-            is_rep = det_union & (idx <= partner)
-            rep = jnp.where(det_union, jnp.minimum(idx, partner), idx)
-            rank2 = jnp.cumsum(is_rep.astype(jnp.int32)) - 1
-            # one device->host transfer serves the label counter, the
-            # removal stat, and the worklist bookkeeping
-            blob2 = np.asarray(jnp.concatenate(
-                [is_rep[None], det_union[None],
-                 jnp.asarray(live_host) & ~det]))
-            n_sccs = int(blob2[0].sum())
-            if n_sccs:
-                labels = jnp.where(det_union,
-                                   next_label + rank2[rep], labels)
-                next_label += n_sccs
-                stats["trim2_sccs"] += n_sccs
-                stats["trim2_removed"] += int(blob2[1].sum())
-                live_host = blob2[2:]
+            keep = np.nonzero(live_host.any(axis=1))[0]
+            if keep.size == 0:
+                continue
+            live_host = _pad_pow2(live_host[keep])
+            B = keep.size                   # real regions; the rest is pad
 
-        keep = np.nonzero(live_host.any(axis=1))[0]
-        if keep.size == 0:
-            gen_span.__exit__(None, None, None)
-            continue
-        live_host = _pad_pow2(live_host[keep])
-        B = keep.size                       # real regions; the rest is pad
+            # one pivot per surviving region: its first live vertex
+            pivots = live_host[:B].argmax(axis=1)
+            stats["pivots"] += B
+            if stats["pivots"] > max_pivots:
+                raise RuntimeError("scc_decompose: pivot budget exceeded")
+            seeds = np.zeros_like(live_host)
+            seeds[np.arange(B), pivots] = True
 
-        # one pivot per surviving region: its first live vertex
-        pivots = live_host[:B].argmax(axis=1)
-        stats["pivots"] += B
-        if stats["pivots"] > max_pivots:
-            gen_span.__exit__(None, None, None)
-            raise RuntimeError("scc_decompose: pivot budget exceeded")
-        seeds = np.zeros_like(live_host)
-        seeds[np.arange(B), pivots] = True
+            fw = sweep(fw_reach, "fw", seeds, live_host, B)
+            bw = sweep(bw_reach, "bw", seeds, live_host, B)
+            live = jnp.asarray(live_host[:B])
+            scc = fw & bw
+            scc_ids = next_label + jnp.arange(B, dtype=jnp.int32)
+            owner = jnp.max(jnp.where(scc, scc_ids[:, None], -1), axis=0)
+            labels = jnp.where(owner >= 0, owner, labels)
+            next_label += B
 
-        # all B pivots advance together: one vmapped dispatch per
-        # direction (per max_batch chunk)
-        def sweep(reach):
-            outs = [reach.run_batch(s, a)
-                    for s, a in zip(_chunks(seeds, max_batch),
-                                    _chunks(live_host, max_batch))]
-            if instrument:
-                stats["reach_rounds"] += int(sum(
-                    np.asarray(o.rounds).sum() for o in outs))
-            return jnp.concatenate([o.mask for o in outs])[:B]
-        fw = sweep(fw_reach)
-        bw = sweep(bw_reach)
-        live = jnp.asarray(live_host[:B])
-        scc = fw & bw
-        scc_ids = next_label + jnp.arange(B, dtype=jnp.int32)
-        owner = jnp.max(jnp.where(scc, scc_ids[:, None], -1), axis=0)
-        labels = jnp.where(owner >= 0, owner, labels)
-        next_label += B
-
-        children = np.asarray(jnp.concatenate(
-            [fw & ~scc, bw & ~scc, live & ~fw & ~bw]))
-        regions = [m for m in children if m.any()]
-        if gen_sp is not None:
-            gen_sp.attrs["pivots"] = B
-        gen_span.__exit__(None, None, None)
+            children = sync(jnp.concatenate(
+                [fw & ~scc, bw & ~scc, live & ~fw & ~bw]))
+            regions = [m for m in children if m.any()]
+            if gen_sp is not None:
+                gen_sp.attrs["pivots"] = B
 
     if ckpt_on and stats["generations"] != last_saved:
         # final state: empty worklist, all labels assigned — a resumed
         # run restores it and returns without replaying any generation
         _save_gen(stats["generations"])
 
-    labels = np.asarray(labels).astype(np.int64)   # the one materialization
+    labels = sync(labels).astype(np.int64)       # the one materialization
     assert ((labels >= 0) | ~region0).all()
     engines = [e for e in (fw_trim, bw_trim, fw_reach, bw_reach)
                if e is not None]
     stats["engine_traces"] = sum(e.traces for e in engines)
-    stats["transpose_builds"] = (sum(e.transpose_builds for e in engines)
-                                 + (0 if use_trim else 1))
+    stats["transpose_builds"] = 1 + sum(e.transpose_builds for e in engines)
     if use_trim:
         stats["trim_dispatches"] = fw_trim.dispatches + bw_trim.dispatches
     stats["reach_dispatches"] = fw_reach.dispatches + bw_reach.dispatches

@@ -51,7 +51,7 @@ import numpy as np
 from .. import obs
 from ..fault.plane import get_fault_plane
 from .common import FrontierPlan, frontier_plan
-from .enginebase import _TRACE_COUNT, EngineBase
+from .enginebase import _TRACE_COUNT, EngineBase, jit_named
 from .graph import CSRGraph, DeltaCSR, TrimResult, _pow2, \
     _stable_counting_order, check_edge_ids
 from .registry import KernelSpec, get_kernel, register_kernel
@@ -257,8 +257,6 @@ def _stream_runner(method: str, use_kernel, full: bool, revivable: bool,
     (per method: from-scratch, deletion-only, and with-insertions
     variants; ``fplan`` bakes the sparse-frontier capacities in,
     DESIGN.md §12)."""
-    import jax
-
     spec = get_kernel(method, family="stream")
 
     def call(tarrs, overlay, state, updates):
@@ -268,7 +266,7 @@ def _stream_runner(method: str, use_kernel, full: bool, revivable: bool,
                         revivable=revivable, frontier=fplan,
                         instrument=instrument, max_rounds=max_rounds)
 
-    return jax.jit(call)
+    return jit_named(call, f"stream_{method}")
 
 
 # -- results -------------------------------------------------------------------

@@ -71,6 +71,7 @@ def bucket_peel_pallas(counters, alive, k, block_v: int = DEFAULT_BLOCK_V,
         ],
         out_specs=pl.BlockSpec((block_v,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
+        name="bucket_peel",
         interpret=interpret,
     )(counters, alive, k)
     return frontier[:n]

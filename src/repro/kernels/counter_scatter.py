@@ -117,6 +117,7 @@ def counter_scatter_pallas(counters, status, upd_src, upd_delta,
             jax.ShapeDtypeStruct((n_pad,), counters.dtype),
             jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
         ],
+        name="counter_scatter",
         interpret=interpret,
     )(counters, status, upd_src[:, None], upd_delta[:, None])
     return out[:n], dead[:n]

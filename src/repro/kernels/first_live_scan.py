@@ -79,6 +79,7 @@ def first_live_scan(flags, valid, active, block_v: int = DEFAULT_BLOCK_V,
             jax.ShapeDtypeStruct((n_pad,), jnp.int32),
             jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
         ],
+        name="first_live_scan",
         interpret=interpret,
     )(flags, valid, active)
     return first[:n], found[:n]

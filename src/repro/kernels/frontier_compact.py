@@ -107,6 +107,7 @@ def prefix_positions(x, block: int = DEFAULT_BLOCK, interpret: bool = True):
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        name="prefix_positions",
         interpret=interpret,
     )(x.reshape(rows, LANES)).reshape(n_pad)
     total = pos[n - 1] + x[n - 1]

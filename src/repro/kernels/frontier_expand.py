@@ -73,6 +73,7 @@ def frontier_expand(flags, valid, pending, block_v: int = DEFAULT_BLOCK_V,
         ],
         out_specs=pl.BlockSpec((block_v,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.bool_),
+        name="frontier_expand",
         interpret=interpret,
     )(flags, valid, pending)
     return hit[:n]

@@ -75,6 +75,7 @@ def segment_sum_pallas(values, seg_ids, num_segments: int,
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda ni, ei: (ni, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
+        name="segment_reduce",
         interpret=interpret,
     )(values, seg_ids.astype(jnp.int32))
     return out[:num_segments]

@@ -1,6 +1,7 @@
 """Observability subsystem (DESIGN.md §11, §13).
 
-Four layers, all opt-in and all zero-cost when off:
+Four layers, all opt-in and all zero-cost when off (a span without a
+trace or recorder costs an inactive ``TraceMe``):
 
 * **device-resident fixpoint telemetry** (``obs.stats``) — per-round
   stats (frontier size, edges traversed, counter decrements) threaded
@@ -11,10 +12,13 @@ Four layers, all opt-in and all zero-cost when off:
   results, identical dispatch and trace counts).
 * **host-side span tracing** (``obs.recorder``) — every
   ``EngineBase._dispatch`` is wrapped in a structured span (engine
-  family, plan signature, wall time, compile-vs-execute attribution)
-  collected by a process-global :class:`Recorder`.  The default global
-  recorder is disabled; install one with :func:`recording` (nested
-  scopes tee spans to both recorders).
+  family, plan signature, wall time, compile-vs-execute attribution).
+  Each span is a ``jax.profiler.TraceAnnotation`` named
+  ``<cat>.<name>`` (``engine.dispatch``, ``scc.sync``), so a profiler
+  trace holds it beside the device's events, and is collected by a
+  process-global :class:`Recorder` when one is enabled.  The default
+  global recorder is disabled; install one with :func:`recording`
+  (nested scopes tee spans to both recorders).
 * **continuous metrics** (``obs.metrics`` + ``obs.memory`` +
   ``obs.profile``) — the process-global :class:`MetricsPlane`: labeled
   counters/gauges/histograms with OpenMetrics exposition, per-engine
@@ -33,12 +37,13 @@ from .metrics import (LABEL_CARDINALITY_CAP, MetricsPlane, MetricsServer,
                       get_plane, load_snapshot, log_buckets,
                       parse_openmetrics, set_plane)
 from .profile import normalize_cost, plan_cost_of, record_plan_cost
-from .recorder import (Recorder, Span, TeeRecorder, get_recorder, instant,
-                       note_kernel, recording, set_recorder, span)
+from .recorder import (Recorder, Span, SpanScope, TeeRecorder,
+                       get_recorder, instant, note_kernel, recording,
+                       set_recorder, span)
 from .stats import RoundStats, round_capacity, stats_init, stats_record
 
 __all__ = [
-    "Recorder", "Span", "TeeRecorder", "get_recorder", "set_recorder",
+    "Recorder", "Span", "SpanScope", "TeeRecorder", "get_recorder", "set_recorder",
     "recording", "span", "instant", "note_kernel",
     "RoundStats", "round_capacity", "stats_init", "stats_record",
     "MetricsPlane", "MetricsServer", "SLOTracker", "RetraceStormWarning",

@@ -1,25 +1,37 @@
 """Process-global span recorder (DESIGN.md §11).
 
-A :class:`Recorder` collects :class:`Span` records — named, categorized
-wall-time intervals with free-form JSON-serializable attributes.  The
-engines' shared ``EngineBase._dispatch`` emits one span per device
-dispatch (engine family, plan signature, compile-vs-execute phase,
-retrace attribution); drivers add their own structural spans (the SCC
-driver's generations, the serving loop's ticks).
+A span is a named, categorized wall-time interval with free-form
+attributes.  The engines' shared ``EngineBase._dispatch`` emits one span
+per device dispatch (engine family, plan signature, compile-vs-execute
+phase, retrace attribution); drivers add their own structural spans (the
+SCC driver's plan, transpose, generations, phases and host syncs; the
+serving loop's ticks).
 
-The process-global recorder is **disabled** by default: ``span()`` on a
-disabled recorder is a no-op context and ``add``/``instant`` return
-immediately, so un-observed runs pay one attribute read per dispatch.
-Install an enabled recorder for a scope with::
+Every ``span(name, cat, **attrs)`` is one ``jax.profiler.TraceAnnotation``
+named ``<cat>.<name>`` (``engine.dispatch``, ``scc.generation``,
+``serve.tick``) with the attributes as its metadata, so it lands in any
+profiler trace on the host clock the device events are aligned to.
+Without an active trace the annotation is an inactive ``TraceMe``.
+
+A :class:`Recorder` additionally collects each span as a :class:`Span`
+record.  The process-global recorder is **disabled** by default: a span
+then records nothing (the ``with`` target is ``None``) and
+``add``/``instant`` return immediately.  Install an enabled recorder for
+a scope with::
 
     with obs.recording() as rec:
         engine.run()
     rec.to_chrome_trace("trace.json")        # chrome://tracing
     rec.to_jsonl("spans.jsonl")              # one span per line
 
-Timestamps are ``time.perf_counter`` seconds relative to the recorder's
-epoch (its construction time), so spans from one recorder share a
-monotonic timeline regardless of wall-clock adjustments.
+Either way the span object keeps its duration in ``seconds`` after it
+exits, from the same ``time.perf_counter`` pair the record uses, for
+callers that keep a counter of it (``scc_decompose``'s ``plan_s``,
+``transpose_s`` and ``sync_s``).
+
+Record timestamps are ``time.perf_counter`` seconds relative to the
+recorder's epoch (its construction time), so spans from one recorder
+share a monotonic timeline regardless of wall-clock adjustments.
 """
 from __future__ import annotations
 
@@ -27,6 +39,8 @@ import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from . import export as _export
 from . import metrics as _metrics
@@ -66,21 +80,20 @@ class Recorder:
         self.epoch = time.perf_counter()
 
     # -- recording ---------------------------------------------------------
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "span", **attrs):
-        """Context manager timing its body.  Yields the mutable
-        :class:`Span` (attrs may be filled in from inside the body);
-        yields ``None`` and records nothing when disabled."""
-        if not self.enabled:
-            yield None
-            return
-        sp = Span(name=name, cat=cat,
-                  ts=time.perf_counter() - self.epoch, attrs=dict(attrs))
-        try:
-            yield sp
-        finally:
-            sp.dur = (time.perf_counter() - self.epoch) - sp.ts
-            self.spans.append(sp)
+    def span(self, name: str, cat: str = "span", **attrs) -> "SpanScope":
+        """Context manager timing its body as one :class:`SpanScope`.
+        Yields the mutable :class:`Span` (attrs may be filled in from
+        inside the body); yields ``None`` and records nothing when
+        disabled."""
+        return SpanScope(self, name, cat, attrs)
+
+    def _open(self, name: str, cat: str, t0: float, attrs) -> Span:
+        return Span(name=name, cat=cat, ts=t0 - self.epoch,
+                    attrs=dict(attrs))
+
+    def _close(self, sp: Span, t0: float, dur: float) -> None:
+        sp.dur = dur
+        self.spans.append(sp)
 
     def add(self, name: str, cat: str = "span", *, ts: float, dur: float,
             **attrs) -> Optional[Span]:
@@ -162,20 +175,13 @@ class TeeRecorder(Recorder):
     def clear(self) -> None:
         self.primary.clear()
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "span", **attrs):
-        t0 = time.perf_counter()
-        sp = Span(name=name, cat=cat, ts=t0 - self.primary.epoch,
-                  attrs=dict(attrs))
-        try:
-            yield sp
-        finally:
-            sp.dur = time.perf_counter() - t0
-            self.primary.spans.append(sp)
-            for rec in self.others:
-                # attrs may have been filled in from inside the body;
-                # forward the final contents.
-                rec.add(sp.name, sp.cat, ts=t0, dur=sp.dur, **sp.attrs)
+    def _close(self, sp: Span, t0: float, dur: float) -> None:
+        sp.dur = dur
+        self.primary.spans.append(sp)
+        for rec in self.others:
+            # attrs may have been filled in from inside the body;
+            # forward the final contents.
+            rec.add(sp.name, sp.cat, ts=t0, dur=dur, **sp.attrs)
 
     def add(self, name: str, cat: str = "span", *, ts: float, dur: float,
             **attrs) -> Optional[Span]:
@@ -199,6 +205,40 @@ class TeeRecorder(Recorder):
     def __repr__(self):
         return (f"TeeRecorder(primary={self.primary!r}, "
                 f"others={len(self.others)})")
+
+
+class SpanScope:
+    """One span: a ``TraceAnnotation`` named ``<cat>.<name>`` around the
+    body, and one :class:`Span` record when the recorder is enabled, both
+    timed by one ``perf_counter`` pair.  ``seconds`` holds the body's
+    duration once the scope has exited, recorder or not."""
+
+    __slots__ = ("recorder", "name", "cat", "attrs", "seconds",
+                 "_annotation", "_t0", "_span")
+
+    def __init__(self, recorder: Recorder, name: str, cat: str, attrs):
+        self.recorder = recorder
+        self.name = name
+        self.cat = cat
+        self.attrs = attrs
+        self.seconds = 0.0
+
+    def __enter__(self) -> Optional[Span]:
+        # TraceMe encodes the metadata only while a trace is active
+        self._annotation = TraceAnnotation(f"{self.cat}.{self.name}",
+                                           **self.attrs)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        self._span = (self.recorder._open(self.name, self.cat, self._t0,
+                                          self.attrs)
+                      if self.recorder.enabled else None)
+        return self._span
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        if self._span is not None:
+            self.recorder._close(self._span, self._t0, self.seconds)
+        self._annotation.__exit__(*exc)
 
 
 _GLOBAL = Recorder(enabled=False)
@@ -242,8 +282,9 @@ def recording(recorder: Optional[Recorder] = None, *, tee: bool = True):
         set_recorder(prev)
 
 
-def span(name: str, cat: str = "span", **attrs):
-    """``get_recorder().span(...)`` — a no-op context when disabled."""
+def span(name: str, cat: str = "span", **attrs) -> SpanScope:
+    """``get_recorder().span(...)``: a profiler annotation always, a
+    record only when the global recorder is enabled."""
     return _GLOBAL.span(name, cat=cat, **attrs)
 
 

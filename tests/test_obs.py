@@ -696,3 +696,226 @@ def test_recording_tee_optout():
                 pass
     assert [sp.name for sp in inner.spans] == ["quiet"]
     assert [sp.name for sp in outer.spans] == []
+
+
+# -- spans on the profiler clock; named device programs -----------------------
+
+def _host_events(log_dir):
+    """(name, start_ns, end_ns, stats) of every host-plane event in the one
+    ``.xplane.pb`` a profiler trace wrote under ``log_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for pl in ProfileData.from_file(path).planes
+            if not pl.name.startswith("/device:")
+            for line in pl.lines for ev in line.events]
+
+
+def test_span_is_a_profiler_annotation_without_a_recorder(tmp_path):
+    import jax
+    assert not obs.get_recorder().enabled
+    with jax.profiler.trace(str(tmp_path)):
+        scope = obs.span("x", cat="t", k=7)
+        with scope as sp:
+            assert sp is None
+        with pytest.raises(ValueError):
+            with obs.span("raised", cat="t"):
+                raise ValueError("boom")
+        with obs.span("after", cat="t"):
+            pass
+    assert scope.seconds > 0
+    events = {name: (s, e, st) for name, s, e, st in _host_events(tmp_path)
+              if name.startswith("t.")}
+    assert set(events) == {"t.x", "t.raised", "t.after"}
+    assert events["t.x"][2] == {"k": 7}
+    # the span a raise left ends before the next one starts
+    assert events["t.raised"][1] <= events["t.after"][0]
+
+
+def test_disabled_span_formats_no_attribute():
+    calls = []
+
+    class Attr:
+        def __str__(self):
+            calls.append("str")
+            return "attr"
+
+        __repr__ = __format__ = __str__
+
+    with obs.span("x", cat="t", a=Attr()):
+        pass
+    assert calls == []
+
+
+def test_span_record_and_seconds_share_one_clock():
+    with obs.recording() as rec:
+        scope = obs.span("x", cat="t")
+        with scope:
+            pass
+    (sp,) = rec.select("x", cat="t")
+    assert sp.dur == scope.seconds
+
+
+def _scc_trace_graph():
+    # trimmed vertices, size-≤2 SCCs and pivots in more than one generation
+    return generators.sink_heavy(300, 1200, 0.9, seed=2)
+
+
+def test_scc_spans_reach_the_profiler_trace(tmp_path):
+    import jax
+    g = _scc_trace_graph()
+    scc_decompose(g)                               # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        labels, stats = scc_decompose(g)
+    assert same_partition(labels, tarjan_oracle(*g.to_numpy()))
+    assert stats["pivots"] > 0 and stats["generations"] >= 2
+    events = _host_events(tmp_path)
+    by_name = {}
+    for ev in events:
+        by_name.setdefault(ev[0], []).append(ev)
+    for name in ("scc.plan", "scc.transpose", "scc.sync", "scc.trim",
+                 "scc.trim2", "scc.reach", "engine.dispatch"):
+        assert name in by_name, name
+    gens = by_name["scc.generation"]
+    assert len(gens) == stats["generations"]
+    assert sorted(ev[3]["gen"] for ev in gens) == \
+        list(range(1, stats["generations"] + 1))
+    assert {ev[3]["dir"] for ev in by_name["scc.reach"]} == {"fw", "bw"}
+
+    def inside_generation(ev):
+        return any(s <= ev[1] and ev[2] <= e for _, s, e, _ in gens)
+
+    for name in ("scc.trim", "scc.trim2", "scc.reach"):
+        assert all(inside_generation(ev) for ev in by_name[name]), name
+    # every sync but the final labels read happens inside a generation
+    outside = [ev for ev in by_name["scc.sync"] if not inside_generation(ev)]
+    assert len(outside) == 1
+    assert outside[0][1] >= max(e for _, _, e, _ in gens)
+    assert all(inside_generation(ev) for ev in by_name["engine.dispatch"])
+
+
+def test_scc_call_seconds_are_kept_on_every_call():
+    import time
+    g = _scc_trace_graph()
+    t0 = time.perf_counter()
+    _, stats = scc_decompose(g)
+    wall = time.perf_counter() - t0
+    seconds = [stats[k] for k in ("plan_s", "transpose_s", "sync_s")]
+    assert all(isinstance(v, float) and v >= 0 for v in seconds)
+    assert stats["transpose_s"] > 0 and stats["sync_s"] > 0
+    assert sum(seconds) <= wall
+    # the counters are the spans' own durations
+    with obs.recording() as rec:
+        _, stats = scc_decompose(g)
+    for key, name in (("plan_s", "plan"), ("transpose_s", "transpose"),
+                      ("sync_s", "sync")):
+        spans = rec.select(name, cat="scc")
+        assert spans
+        assert stats[key] == pytest.approx(sum(sp.dur for sp in spans),
+                                           rel=1e-12, abs=1e-12)
+    _, empty = scc_decompose(generators.chain(0))
+    assert (empty["plan_s"], empty["transpose_s"], empty["sync_s"]) == \
+        (0.0, 0.0, 0.0)
+
+
+@pytest.mark.parametrize("path", ["continue", "pivot-budget", "fault"])
+def test_scc_generation_span_closes_on_every_path(path):
+    from repro import fault as flt
+    g = (generators.layered_dag(200, 11, 4, seed=1) if path == "continue"
+         else _scc_trace_graph())
+    with obs.recording() as rec:
+        if path == "continue":
+            # a DAG trims away whole: the first generation leaves by
+            # `continue` before any pivot
+            _, stats = scc_decompose(g)
+            assert stats["pivots"] == 0
+        elif path == "pivot-budget":
+            with pytest.raises(RuntimeError, match="pivot budget"):
+                scc_decompose(g, max_pivots=0)
+        else:
+            with flt.injecting_faults(
+                    flt.FaultSchedule(0, at={"pre-dispatch": [1]})):
+                with pytest.raises(flt.DeviceFault):
+                    scc_decompose(g)
+        with obs.span("after", cat="t"):
+            pass
+    gens = rec.select("generation", cat="scc")
+    assert len(gens) == 1         # recorded, so closed, on the way out
+    after = rec.select("after", cat="t")[0]
+    assert gens[0].ts + gens[0].dur <= after.ts
+
+
+def _dispatched_modules(monkeypatch, drive):
+    """Module names of the jitted runners ``drive()`` dispatches."""
+    import re
+
+    from repro.core.enginebase import EngineBase
+    seen = []
+    real = EngineBase._dispatch
+
+    def spy(self, fn, *args):
+        seen.append((fn, args))
+        return real(self, fn, *args)
+
+    monkeypatch.setattr(EngineBase, "_dispatch", spy)
+    drive()
+    return {re.search(r"module @(\S+)", fn.lower(*args).as_text()).group(1)
+            for fn, args in seen}
+
+
+def _drive(path, g):
+    masks = np.ones((2, g.n), bool)
+    if path == "trim":                  # kron-s22.trim's call
+        plan(g, method="ac6", transpose=g.transpose()).run()
+    elif path == "trim-batch":
+        plan(g, method="ac6").run_batch(masks)
+    elif path == "scc":                 # urand-s18.scc's call
+        scc_decompose(g, trim_method="ac6")
+    elif path == "reach":
+        plan_reach(g, backend="windowed").run(0)
+    elif path == "reach-push-batch":
+        plan_reach(g, backend="dense").run_batch(np.eye(2, g.n, dtype=bool))
+    elif path == "peel":
+        plan_peel(g).run()
+    elif path == "peel-batch":
+        plan_peel(g).run_batch(masks)
+    elif path == "stream":
+        indptr, indices = g.to_numpy()
+        u = int(np.argmax(np.diff(indptr) > 0))     # delete u's first arc
+        plan_stream(g).apply(deletions=(np.array([u]),
+                                        indices[indptr[u]:indptr[u] + 1]))
+    elif path == "sharded":
+        plan(g, method="ac6", backend="sharded").run()
+
+
+@pytest.mark.parametrize("path,modules", [
+    ("trim", {"jit_trim_ac6"}),
+    ("trim-batch", {"jit_trim_ac6_batch"}),
+    ("scc", {"jit_trim_ac6_batch", "jit_reach_pull_batch"}),
+    ("reach", {"jit_reach_pull"}),
+    ("reach-push-batch", {"jit_reach_push_batch"}),
+    ("peel", {"jit_peel_bucket"}),
+    ("peel-batch", {"jit_peel_bucket_batch"}),
+    ("stream", {"jit_stream_ac4"}),
+    ("sharded", {"jit_trim_ac6_sharded"}),
+])
+def test_jitted_runners_name_their_modules(monkeypatch, path, modules):
+    g = generators.erdos_renyi(200, 800, seed=3)
+    assert _dispatched_modules(monkeypatch, lambda: _drive(path, g)) == \
+        modules
+
+
+def test_trim2_runner_names_its_module():
+    import jax.numpy as jnp
+
+    from repro.core.scc import _trim2_runner
+    g = generators.erdos_renyi(200, 800, seed=3)
+    gt = g.transpose()
+    text = _trim2_runner().lower(g.indptr, g.indices, gt.indptr,
+                                 gt.indices, jnp.ones((2, g.n), bool)
+                                 ).as_text()
+    assert "module @jit_scc_trim2_batch " in text

@@ -76,15 +76,46 @@ def _kernel_case(name: str, sds):
     return functools.partial(fn, interpret=False, **kw), args
 
 
+#: the ``name=`` of the pallas_call each wrapper makes; frontier_compact
+#: and sparse_expand reach Pallas through the prefix_positions scan
+PALLAS_NAMES = {"first_live_scan": "first_live_scan",
+                "frontier_expand": "frontier_expand",
+                "bucket_peel": "bucket_peel",
+                "counter_scatter": "counter_scatter",
+                "frontier_compact": "prefix_positions",
+                "sparse_expand": "prefix_positions",
+                "prefix_positions": "prefix_positions",
+                "segment_reduce": "segment_reduce"}
+
+
 @pytest.mark.parametrize("kernel", [
     "first_live_scan", "frontier_expand", "bucket_peel", "counter_scatter",
     "frontier_compact", "sparse_expand"])
 def test_graph_kernel_compiles_for_v5e(one_chip, kernel):
+    import re
+
     import jax
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fn, args = _kernel_case(kernel, sds)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name= names its Mosaic custom call, which is what a
+    # device trace's XLA Ops line shows
+    name = PALLAS_NAMES[kernel]
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(.*"
+                     rf'custom_call_target="tpu_custom_call".*'
+                     rf'op_name="[^"]*/{name}/pallas_call"', text)
+
+
+@pytest.mark.parametrize("kernel", sorted(PALLAS_NAMES))
+def test_graph_kernel_names_its_pallas_call(kernel):
+    """Every graph kernel's pallas_call carries its ``name=``, also the
+    GNN segment sum that no chip path compiles (captured abstractly)."""
+    from repro.analysis.catalog import KERNEL_CATALOG
+    entry = next(e for e in KERNEL_CATALOG if e.name == kernel)
+    calls = entry.build(entry.points[0])
+    assert calls
+    assert {c.kwargs.get("name") for c in calls} == {PALLAS_NAMES[kernel]}
