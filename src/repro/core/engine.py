@@ -5,8 +5,9 @@ this module gives them the matching API.  ``plan()`` resolves a method from
 the kernel registry, binds a backend, and returns a :class:`TrimEngine`
 that amortizes every per-call cost the old one-shot ``trim()`` paid:
 
-* the transpose (AC-4's Gᵀ, SCC's backward graph) is built once — a true
-  O(n+m) counting sort — and cached on the engine;
+* the transpose (AC-4's Gᵀ, SCC's backward graph) is built once — a
+  device sort where G lives on an accelerator, else a host O(n+m)
+  counting sort — and cached on the engine;
 * the kernel is traced/compiled once per (shape, method, workers)
   signature and shared process-wide, so a worklist of ``run()`` calls
   (the SCC driver's regions) reuses one executable;

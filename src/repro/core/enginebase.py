@@ -9,8 +9,9 @@ The repo has two engine families over the same lifecycle:
   most of its time in.
 
 Both amortize the same per-call costs: a transpose built at most once
-(O(n+m) counting sort, pre-seedable so a FW/BW engine pair shares one
-build), a jitted kernel traced once per static configuration, and
+(``CSRGraph.transpose``: a device sort where G lives on an accelerator,
+else a host O(n+m) counting sort; pre-seedable so a FW/BW engine pair
+shares one build), a jitted kernel traced once per static configuration, and
 device-resident results.  This module holds the plumbing they share:
 
 * ``_TRACE_COUNT`` — process-wide count of kernel traces, bumped from
@@ -94,7 +95,9 @@ class EngineBase:
     # -- cached resources --------------------------------------------------
     @property
     def transpose(self) -> CSRGraph:
-        """Gᵀ, built at most once (O(n+m) counting sort) and cached."""
+        """Gᵀ, built at most once and cached: on G's device when G lives
+        on an accelerator, else by the host counting sort
+        (:meth:`CSRGraph.transpose`)."""
         if self._transpose is None:
             self._transpose = self.graph.transpose()
             self._transpose_builds += 1

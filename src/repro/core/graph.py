@@ -5,11 +5,15 @@ The paper stores explicit graphs in CSR (compressed sparse row) format
 (``indices``).  We keep both arrays as device arrays so every algorithm is
 jit-able with static (n, m).
 
-Construction and transposition are true O(n + m) counting sorts (no
-comparison sort anywhere), mirroring the paper's assumption that AC-4 pays
-the full O(n+m) space — but only linear time — for reverse edges.  The
-transpose is built at most once per :class:`repro.core.engine.TrimEngine`
-and cached for every subsequent run (DESIGN.md §1).
+Construction is a true O(n + m) counting sort on the host, mirroring the
+paper's assumption that AC-4 pays the full O(n+m) space — but only linear
+time — for reverse edges.  The transpose is built where G lives: on an
+accelerator by one jitted stable sort of the arcs by target (program
+``jit_csr_transpose``), so nothing crosses to the host; for a numpy- or
+CPU-backed graph by the same host counting sort as construction, which
+is faster there.  Both give the same arrays.  The transpose is built at
+most once per :class:`repro.core.engine.TrimEngine` and cached for every
+subsequent run (DESIGN.md §1).
 """
 from __future__ import annotations
 
@@ -121,8 +125,21 @@ class CSRGraph:
         return CSRGraph(jnp.asarray(indptr, jnp.int32),
                         jnp.asarray(dst, jnp.int32))
 
+    @property
+    def on_accelerator(self) -> bool:
+        """Whether ``indices`` is a device array on a non-CPU backend:
+        the case in which :meth:`transpose` builds Gᵀ on the device."""
+        return isinstance(self.indices, jax.Array) and all(
+            d.platform != "cpu" for d in self.indices.devices())
+
     def transpose(self) -> "CSRGraph":
-        """Counting-sort transpose (numpy, host side): Gᵀ, O(n + m)."""
+        """Gᵀ, with each row's sources in ascending edge-id order.
+
+        On an accelerator, :func:`csr_transpose` on G's device: dispatched
+        asynchronously, nothing read to the host or uploaded.  Otherwise
+        the host counting sort, O(n + m)."""
+        if self.on_accelerator:
+            return CSRGraph(*csr_transpose(self.indptr, self.indices))
         indptr = np.asarray(self.indptr)
         indices = np.asarray(self.indices)
         n = self.n
@@ -143,6 +160,21 @@ def row_ids(indptr: jax.Array, m: int) -> jax.Array:
     # vertices with zero degree contribute stacked marks at the same index;
     # cumsum handles that correctly.
     return jnp.cumsum(marks)
+
+
+@jax.jit
+def csr_transpose(indptr: jax.Array, indices: jax.Array):
+    """Gᵀ's ``(indptr, indices)`` from G's, on the device: a stable sort
+    of the arcs by target keeps each row's sources in edge-id order, and
+    Gᵀ's row starts are the prefix sums of the in-degrees.  The same
+    int32 arrays as the host counting sort of :meth:`CSRGraph.transpose`.
+    """
+    n, m = indptr.shape[0] - 1, indices.shape[0]
+    _, src = jax.lax.sort((indices, row_ids(indptr, m)), num_keys=1,
+                          is_stable=True)
+    in_deg = jnp.bincount(indices, length=n).astype(jnp.int32)
+    indptr_t = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(in_deg)])
+    return indptr_t, src
 
 
 class TrimResult:

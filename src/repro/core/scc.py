@@ -56,8 +56,11 @@ from .enginebase import jit_named
 from .graph import CSRGraph
 from .reach import plan_reach
 
-#: ``stats`` entries that time one call (seconds); never checkpointed
+#: ``stats`` entries that time one call (seconds)
 _CALL_SECONDS = ("plan_s", "transpose_s", "sync_s")
+#: ``stats`` entries of one call, never checkpointed: its seconds and
+#: where it built Gᵀ
+_CALL_STATS = _CALL_SECONDS + ("transpose_on_device",)
 
 
 def _pad_pow2(masks: np.ndarray) -> np.ndarray:
@@ -219,7 +222,9 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
 
     Every call is traced as ``obs`` spans (cat ``"scc"``), which a
     profiler trace holds as ``scc.<name>`` annotations and an
-    ``obs.recording()`` around the call as records: ``transpose`` (the
+    ``obs.recording()`` around the call as records: ``transpose``
+    (``where="device"|"host"``: on an accelerator the dispatch of Gᵀ's
+    device sort, whose device time the first ``sync`` waits on; else the
     host counting sort of Gᵀ and its upload), ``plan`` (the four
     engines), one ``generation`` per worklist generation (its region
     count, and its pivots once chosen) and inside it ``trim``, ``trim2``,
@@ -228,8 +233,9 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
     blobs, the children masks and the final labels).  The same spans
     fill three float ``stats`` entries on every call:
     ``stats["transpose_s"]``, ``stats["plan_s"]`` and ``stats["sync_s"]``,
-    the seconds spent in those spans.  They time this call alone and are
-    not checkpointed.
+    the seconds spent in those spans, and the int
+    ``stats["transpose_on_device"]``, 1 where Gᵀ was built on the device.
+    They describe this call alone and are not checkpointed.
 
     ``checkpoint_dir`` + ``checkpoint_every=k`` (DESIGN.md §14) save the
     generation-level driver state — labels, the pending region worklist,
@@ -254,6 +260,7 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
              "trim_rounds": 0 if instrument else None,
              "reach_rounds": 0 if instrument else None,
              "engine_traces": 0, "transpose_builds": 1,
+             "transpose_on_device": 0,
              **dict.fromkeys(_CALL_SECONDS, 0.0)}
     if n == 0:
         return np.zeros(0, np.int64), stats
@@ -298,7 +305,10 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
     # four engines, one transpose build: the forward engines are
     # pre-seeded with Gᵀ, the backward pair sweeps Gᵀ with its transpose
     # cache pre-seeded with G itself
-    with span("transpose", "transpose_s"):
+    on_device = graph.on_accelerator
+    stats["transpose_on_device"] = int(on_device)
+    with span("transpose", "transpose_s",
+              where="device" if on_device else "host"):
         gt = graph.transpose()                # the one and only build
     with span("plan", "plan_s"):
         fw_trim = bw_trim = None
@@ -344,7 +354,7 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
         if counters:
             tree["per_worker_edges"] = stats["per_worker_edges"]
         drv_stats = {k: v for k, v in stats.items()
-                     if k != "per_worker_edges" and k not in _CALL_SECONDS}
+                     if k != "per_worker_edges" and k not in _CALL_STATS}
         save_tree(checkpoint_dir, gens, tree,
                   {"driver": {"kind": "scc", "next_label": next_label,
                               "stats": drv_stats}},

@@ -785,6 +785,9 @@ def test_scc_spans_reach_the_profiler_trace(tmp_path):
     assert sorted(ev[3]["gen"] for ev in gens) == \
         list(range(1, stats["generations"] + 1))
     assert {ev[3]["dir"] for ev in by_name["scc.reach"]} == {"fw", "bw"}
+    # a CPU-resident graph builds Gᵀ by the host counting sort
+    assert [ev[3]["where"] for ev in by_name["scc.transpose"]] == ["host"]
+    assert stats["transpose_on_device"] == 0
 
     def inside_generation(ev):
         return any(s <= ev[1] and ev[2] <= e for _, s, e, _ in gens)
