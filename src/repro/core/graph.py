@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
+
 LIVE = np.int32(1)
 DEAD = np.int32(0)
 
@@ -315,6 +317,8 @@ class DeltaCSR:
     ``compact()`` folds the overlay into a fresh base CSR through the
     existing O(n+m) counting-sort constructor once
     ``overlay_fraction`` crosses ``load_factor`` (the engine triggers it).
+    Building the host mirrors and the sorted key index, at construction
+    and at each compaction, is the ``stream.index`` span.
     """
 
     def __init__(self, base: CSRGraph, *, capacity: int = 256,
@@ -331,15 +335,17 @@ class DeltaCSR:
     def _rebase(self, base: CSRGraph):
         self.base = base
         n, m = base.n, base.m
-        indptr, indices = base.to_numpy()
-        self._src_np = np.repeat(np.arange(n, dtype=np.int64),
-                                 np.diff(indptr))
-        self._dst_np = indices.astype(np.int64)
-        # O(m log m) one-time index for (u, v) -> edge-id lookup; duplicate
-        # arcs occupy a contiguous key range and are resolved instance-wise
-        keys = self._src_np * max(n, 1) + self._dst_np
-        self._key_order = np.argsort(keys, kind="stable")
-        self._keys_sorted = keys[self._key_order]
+        with obs.span("index", cat="stream"):
+            indptr, indices = base.to_numpy()
+            self._src_np = np.repeat(np.arange(n, dtype=np.int64),
+                                     np.diff(indptr))
+            self._dst_np = indices.astype(np.int64)
+            # O(m log m) one-time index for (u, v) -> edge-id lookup;
+            # duplicate arcs occupy a contiguous key range and are
+            # resolved instance-wise
+            keys = self._src_np * max(n, 1) + self._dst_np
+            self._key_order = np.argsort(keys, kind="stable")
+            self._keys_sorted = keys[self._key_order]
         self._tomb_np = np.zeros(m, bool)
         cap = self.capacity
         self._ins_src_np = np.full(cap, n, np.int64)   # n = empty sentinel

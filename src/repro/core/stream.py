@@ -61,6 +61,20 @@ STREAM_BACKENDS = ("dense",)
 _STAT_NAMES = ("r_frontier", "r_edges", "r_decrements")
 
 
+def _batch_tier(plan: FrontierPlan, width: int, n: int,
+                m: int) -> FrontierPlan | None:
+    """The sparse tier an apply tries before ``plan``'s own, sized from
+    the batch: a batch of ``width`` padded updates seeds at most that many
+    dead sources, and its cascade rounds stay near that size.  ``cap`` is
+    the width (at least 128), ``ecap`` four mean in-degrees a member;
+    ``None`` where that is no smaller than ``plan``."""
+    cap = min(_pow2(max(width, 128)), plan.cap)
+    ecap = min(_pow2(4 * cap * max(m // max(n, 1), 1)), plan.ecap)
+    if (cap, ecap) == (plan.cap, plan.ecap):
+        return None
+    return FrontierPlan(plan.mode, cap, ecap)
+
+
 # -- the stream kernel (family "stream") ---------------------------------------
 
 def _run_stream_ac4(tarrs, overlay, state, updates, *, use_kernel,
@@ -96,8 +110,11 @@ def _run_stream_ac4(tarrs, overlay, state, updates, *, use_kernel,
              rows (tombstones masked through the expansion's edge
              positions), and scatter-add the bounded buffer; the small
              insert-buffer contribution stays a dense segment-sum either
-             way.  The decrement vector — and therefore the fixpoint and
-             every stat — is bit-identical to the dense path.
+             way.  A round that fits the smaller tier sized from the
+             batch (:func:`_batch_tier`) takes it first, so a cascade's
+             round costs in proportion to the batch, not to m.  The
+             decrement vector — and therefore the fixpoint and every
+             stat — is bit-identical to the dense path.
     instrument: static — thread per-round fixpoint telemetry (processed
              frontier size, live arcs traversed, counter decrements
              applied to live vertices; DESIGN.md §11) through the loop
@@ -178,18 +195,22 @@ def _run_stream_ac4(tarrs, overlay, state, updates, *, use_kernel,
     if sparse:
         t_deg = t_indptr[1:] - t_indptr[:-1]
         mt = t_indices.shape[0]
+        # smallest first: fitting a tier implies fitting every larger one
+        tiers = [p for p in (_batch_tier(frontier, del_src.shape[0]
+                                         + add_src.shape[0], n, mt),
+                             frontier) if p is not None]
 
     def base_dec_dense(f):
         return jax.ops.segment_sum((f[t_rows] & ~tomb_t).astype(jnp.int32),
                                    t_indices, num_segments=n)
 
-    def base_dec_sparse(f):
+    def base_dec_sparse(tier, f):
         # expand only the frontier's Gᵀ rows; a tombstoned base arc is
         # masked through its expanded edge *position* (Gᵀ order), exactly
         # the arcs ``~tomb_t`` drops from the dense segment-sum
-        ids, _ = kops.frontier_compact(f, frontier.cap)
+        ids, _ = kops.frontier_compact(f, tier.cap)
         _, tgt, pos, valid = kops.sparse_expand(t_indptr, t_indices, ids,
-                                                frontier.ecap)
+                                                tier.ecap)
         if mt:          # an edgeless base (everything compacted away or
             # inserted) expands to no valid slots — nothing to tombstone
             valid = valid & ~tomb_t[jnp.clip(pos, 0, mt - 1)]
@@ -204,9 +225,13 @@ def _run_stream_ac4(tarrs, overlay, state, updates, *, use_kernel,
         if sparse:
             count = jnp.sum(f)
             tedges = jnp.sum(jnp.where(f, t_deg, 0))
-            sparse_ok = (count <= frontier.cap) & (tedges <= frontier.ecap)
-            dec = jax.lax.cond(sparse_ok, base_dec_sparse, base_dec_dense,
-                               f)
+            # the index of the smallest tier the round fits, else dense
+            tier = sum(((count > p.cap) | (tedges > p.ecap)).astype(
+                jnp.int32) for p in tiers)
+            sparse_ok = tier < len(tiers)
+            dec = jax.lax.switch(
+                tier, [functools.partial(base_dec_sparse, p) for p in tiers]
+                + [base_dec_dense], f)
         else:
             dec = base_dec_dense(f)
         dec = dec + jax.ops.segment_sum(
@@ -279,15 +304,20 @@ class StreamResult:
     rounds:  incremental propagation rounds this batch ran
     dirty:   the batch contained a reviving insertion and fell back to the
              from-scratch initialization (still one dispatch)
+    resolve_s: host seconds of the batch's ``stream.resolve`` span
+             (overlay resolution and padded upload)
     """
 
-    __slots__ = ("_status", "_rounds", "_dirty", "_round_stats")
+    __slots__ = ("_status", "_rounds", "_dirty", "_round_stats",
+                 "resolve_s")
 
-    def __init__(self, status, rounds, dirty, round_stats=None):
+    def __init__(self, status, rounds, dirty, round_stats=None,
+                 resolve_s=0.0):
         self._status = status
         self._rounds = rounds
         self._dirty = dirty
         self._round_stats = round_stats
+        self.resolve_s = resolve_s
 
     @property
     def status(self):
@@ -418,23 +448,26 @@ class StreamEngine(EngineBase):
     # -- cached resources --------------------------------------------------
     def _transpose_arrays(self):
         """Base Gᵀ arrays plus the base-edge→transpose-edge permutation
-        (int32), rebuilt only at compaction."""
+        (int32), rebuilt only at compaction: a host counting sort of the
+        overlay's mirrors and one upload, spanned as ``stream.transpose``.
+        Any Gᵀ the caller holds is not used."""
         if self._tarrs is None:
             import jax.numpy as jnp
             base = self.delta.base
             n, m = base.n, base.m
-            indices = self.delta._dst_np
-            src = self.delta._src_np      # edge sources, held by the overlay
-            perm = _stable_counting_order(indices, n)
-            t_counts = (np.bincount(indices, minlength=n) if m
-                        else np.zeros(n, np.int64))
-            t_indptr = np.zeros(n + 1, dtype=np.int32)
-            np.cumsum(t_counts, out=t_indptr[1:])
-            t_indices = src[perm]
-            t_rows = np.repeat(np.arange(n, dtype=np.int64), t_counts)
-            self._tarrs = tuple(
-                jnp.asarray(a, jnp.int32)
-                for a in (t_indptr, t_indices, t_rows, perm))
+            with obs.span("transpose", cat="stream"):
+                indices = self.delta._dst_np
+                src = self.delta._src_np  # edge sources, held by the overlay
+                perm = _stable_counting_order(indices, n)
+                t_counts = (np.bincount(indices, minlength=n) if m
+                            else np.zeros(n, np.int64))
+                t_indptr = np.zeros(n + 1, dtype=np.int32)
+                np.cumsum(t_counts, out=t_indptr[1:])
+                t_indices = src[perm]
+                t_rows = np.repeat(np.arange(n, dtype=np.int64), t_counts)
+                self._tarrs = tuple(
+                    jnp.asarray(a, jnp.int32)
+                    for a in (t_indptr, t_indices, t_rows, perm))
             # seed the EngineBase cache so .transpose is consistent
             if self._transpose is None:
                 self._transpose = CSRGraph(self._tarrs[0], self._tarrs[1])
@@ -513,6 +546,10 @@ class StreamEngine(EngineBase):
         Deleting an edge that is not present raises ``ValueError`` (and
         leaves the batch unapplied).  One device dispatch; the update
         arrays are pow2-padded so repeated batch sizes never retrace.
+        The batch's resolution against the overlay and its padded upload
+        are the ``stream.resolve`` span, whose seconds the result keeps
+        as ``resolve_s``; a compaction that frees the insert buffer runs
+        before it.
         """
         dsrc, ddst = self._pairs(deletions)
         isrc, idst = self._pairs(insertions)
@@ -538,20 +575,23 @@ class StreamEngine(EngineBase):
             self.compact()          # free the insert buffer first
             if isrc.size > d.capacity:
                 d.grow(isrc.size)
-        eids, slots_del = d.resolve_deletions(dsrc, ddst)
-        slots_ins = d.stage_inserts(isrc, idst)
+        resolve = obs.span("resolve", cat="stream")
+        with resolve:
+            eids, slots_del = d.resolve_deletions(dsrc, ddst)
+            slots_ins = d.stage_inserts(isrc, idst)
+            updates = self._padded_updates(dsrc, ddst, eids, slots_del,
+                                           isrc, idst, slots_ins)
         fn = _stream_runner(self.method, self.use_kernel, full=False,
                             revivable=bool(isrc.size), fplan=self.fplan,
                             instrument=self.instrument,
                             max_rounds=self.max_rounds)
         overlay, state, rounds, dirty, stats = self._dispatch(
             fn, self._transpose_arrays(), self._overlay_arrays(),
-            self._state,
-            self._padded_updates(dsrc, ddst, eids, slots_del, isrc, idst,
-                                 slots_ins))
+            self._state, updates)
         self._write_back(overlay, state, rounds)
         res = StreamResult(state[0], rounds, dirty,
-                           round_stats=self._wrap_stats(rounds, stats))
+                           round_stats=self._wrap_stats(rounds, stats),
+                           resolve_s=resolve.seconds)
         if d.needs_compact:
             self.compact()
         return res
@@ -655,8 +695,10 @@ class StreamEngine(EngineBase):
         """Fold the overlay into a fresh base CSR (O(n+m) counting sort)
         and rebuild the transpose/permutation caches.  The fixpoint state
         is untouched — compaction changes the representation, not the
-        graph."""
-        self.graph = self.delta.compact()
+        graph.  Spanned as ``stream.compact``; the transpose is rebuilt
+        at the next dispatch."""
+        with obs.span("compact", cat="stream"):
+            self.graph = self.delta.compact()
         self._transpose = None
         self._tarrs = None
         self._compactions += 1

@@ -242,6 +242,47 @@ def test_apply_same_batch_shape_never_retraces():
     assert engine.traces == traces
 
 
+# -- the batch-sized sparse tier ---------------------------------------------
+
+def test_batch_tier_sizes():
+    from repro.core.common import FrontierPlan, frontier_plan
+    from repro.core.stream import _batch_tier
+    n, m = 1 << 22, 1 << 26            # kron-s22: 1024 deletions + insert pad
+    plan22 = frontier_plan("auto", n, m)
+    assert plan22 == FrontierPlan("auto", 65536, 8388608)
+    assert _batch_tier(plan22, 1025, n, m) == FrontierPlan("auto", 2048,
+                                                           131072)
+    assert _batch_tier(plan22, 2, n, m) == FrontierPlan("auto", 128, 8192)
+    # a plan no larger than the batch's tier keeps its one tier
+    small = frontier_plan("auto", 1024, 16384)
+    assert _batch_tier(small, 1025, 1024, 16384) is None
+
+
+@pytest.mark.parametrize("batch", [64, 512])
+def test_batch_tier_stream_matches_dense_and_oracle(batch):
+    """A plan with both sparse tiers (n = 16384, m = 131072): every apply
+    equals the dense-frontier engine, rounds included, and the oracle."""
+    from repro.core.stream import _batch_tier
+    g = generators.rmat(14, 1 << 17, seed=5)
+    auto = plan_stream(g, frontier="auto", instrument=True)
+    assert _batch_tier(auto.fplan, batch + 1, g.n, g.m) is not None
+    dense = plan_stream(g, frontier="dense")
+    rng = np.random.default_rng(batch)
+    rounds = sparse = 0
+    for _ in range(8):
+        src, dst = _edges(auto)
+        ids = rng.choice(src.size, batch, replace=False)
+        a = auto.apply(deletions=(src[ids], dst[ids]))
+        d = dense.apply(deletions=(src[ids], dst[ids]))
+        assert a.rounds == d.rounds
+        assert np.array_equal(np.asarray(a.status), np.asarray(d.status))
+        assert np.array_equal(np.asarray(a.status),
+                              trim_oracle(*auto.snapshot().to_numpy()))
+        rounds += a.rounds
+        sparse += int(a.round_stats.total("r_sparse"))
+    assert rounds > 0 and sparse == rounds
+
+
 def test_plan_stream_rejects_unknown_configs():
     g = generators.cycle(4)
     with pytest.raises(ValueError, match="unknown method"):
