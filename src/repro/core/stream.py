@@ -86,8 +86,12 @@ def _run_stream_ac4(tarrs, overlay, state, updates, *, use_kernel,
 
     tarrs:   (t_indptr, t_indices, t_rows, perm) — base Gᵀ plus the
              permutation mapping Gᵀ edge order back to base edge order
-             (``perm``), so the base tombstone mask can be gathered into
-             transpose order once per step.
+             (``perm``), through which the base tombstone mask is read in
+             transpose order: whole, once per step, where every round
+             reads all of it (a dense plan, or ``full``); otherwise only
+             in the branches that read it — at a sparse round's expanded
+             positions, or whole inside a dense round or the revival
+             rebuild.
     overlay: (tomb, ins_src, ins_dst, ins_alive) — device overlay arrays.
     state:   (status bool (n,), counters int32 (n,)) — the persistent
              AC-4 state; ``counters[v]`` = number of live out-arcs of a
@@ -141,7 +145,15 @@ def _run_stream_ac4(tarrs, overlay, state, updates, *, use_kernel,
     ins_src = ins_src.at[add_slot].set(add_src, mode="drop")
     ins_dst = ins_dst.at[add_slot].set(add_dst, mode="drop")
     ins_alive = ins_alive.at[add_slot].set(True, mode="drop")
-    tomb_t = tomb[perm]                      # tombstones in Gᵀ edge order
+    # tombstones in Gᵀ edge order: an m-wide gather, hoisted out of the
+    # loop only where every round pays it anyway; a sparse round reads
+    # its ecap positions through ``perm`` instead
+    hoist = full or frontier.mode == "dense"
+    if hoist:
+        tomb_t = tomb[perm]
+
+    def tomb_all():
+        return tomb_t if hoist else tomb[perm]
 
     def stat(ids):
         return status[jnp.clip(ids, 0, hi)] & (ids < n)
@@ -159,8 +171,8 @@ def _run_stream_ac4(tarrs, overlay, state, updates, *, use_kernel,
     def scratch_init(_):
         # from-scratch: all vertices live, counters = live out-degree
         # over the overlay (two segment-sums)
-        deg0 = jax.ops.segment_sum((~tomb_t).astype(jnp.int32), t_indices,
-                                   num_segments=n)
+        deg0 = jax.ops.segment_sum((~tomb_all()).astype(jnp.int32),
+                                   t_indices, num_segments=n)
         deg0 = deg0 + jax.ops.segment_sum(ins_alive.astype(jnp.int32),
                                           jnp.clip(ins_src, 0, hi),
                                           num_segments=n)
@@ -201,19 +213,20 @@ def _run_stream_ac4(tarrs, overlay, state, updates, *, use_kernel,
                              frontier) if p is not None]
 
     def base_dec_dense(f):
-        return jax.ops.segment_sum((f[t_rows] & ~tomb_t).astype(jnp.int32),
-                                   t_indices, num_segments=n)
+        return jax.ops.segment_sum(
+            (f[t_rows] & ~tomb_all()).astype(jnp.int32), t_indices,
+            num_segments=n)
 
     def base_dec_sparse(tier, f):
         # expand only the frontier's Gᵀ rows; a tombstoned base arc is
         # masked through its expanded edge *position* (Gᵀ order), exactly
-        # the arcs ``~tomb_t`` drops from the dense segment-sum
+        # the arcs ``~tomb_all()`` drops from the dense segment-sum
         ids, _ = kops.frontier_compact(f, tier.cap)
         _, tgt, pos, valid = kops.sparse_expand(t_indptr, t_indices, ids,
                                                 tier.ecap)
         if mt:          # an edgeless base (everything compacted away or
             # inserted) expands to no valid slots — nothing to tombstone
-            valid = valid & ~tomb_t[jnp.clip(pos, 0, mt - 1)]
+            valid = valid & ~tomb[perm[jnp.clip(pos, 0, mt - 1)]]
         return jnp.zeros((n,), jnp.int32).at[
             jnp.where(valid, tgt, n)].add(1, mode="drop")
 

@@ -258,29 +258,163 @@ def test_batch_tier_sizes():
     assert _batch_tier(small, 1025, 1024, 16384) is None
 
 
-@pytest.mark.parametrize("batch", [64, 512])
-def test_batch_tier_stream_matches_dense_and_oracle(batch):
-    """A plan with both sparse tiers (n = 16384, m = 131072): every apply
-    equals the dense-frontier engine, rounds included, and the oracle."""
+def _fan_graph(fans, leaves, fan_in, seed=5):
+    """``generators.rmat(14, 1 << 17)`` plus ``fans`` fans on new vertices.
+    Fan j's hub has one arc into G's live core and ``leaves`` in-arcs, one
+    out of each of its leaves; each leaf is fed by ``fan_in`` arcs out of
+    live core vertices.  Deleting the hub's arc kills the hub (round 1),
+    then its leaves (round 2), whose Gᵀ rows hold ``fan_in`` arcs each.
+    Returns the graph, and per fan its hub's arc and its leaves' in-arcs,
+    each as a (2, k) array of sources over targets."""
+    g = generators.rmat(14, 1 << 17, seed=seed)
+    indptr, indices = g.to_numpy()
+    core = np.flatnonzero(trim_oracle(indptr, indices))
+    rng = np.random.default_rng(seed)
+    arcs = [np.stack([np.repeat(np.arange(g.n), np.diff(indptr)), indices])]
+    hub_arcs, leaf_in = [], []
+    for j in range(fans):
+        hub = g.n + j * (leaves + 1)
+        leaf = hub + 1 + np.arange(leaves)
+        hub_arcs.append(np.array([[hub], [rng.choice(core)]]))
+        leaf_in.append(np.stack([rng.choice(core, leaves * fan_in),
+                                 np.repeat(leaf, fan_in)]))
+        arcs += [hub_arcs[-1], np.stack([leaf, np.full(leaves, hub)]),
+                 leaf_in[-1]]
+    arcs = np.concatenate(arcs, axis=1)
+    return (CSRGraph.from_edges(g.n + fans * (leaves + 1), *arcs),
+            hub_arcs, leaf_in)
+
+
+def _assert_counters_live_out(engine):
+    """A live vertex's AC-4 counter is its number of live successors."""
+    indptr, indices = engine.snapshot().to_numpy()
+    live = trim_oracle(indptr, indices)
+    src = np.repeat(np.arange(live.size), np.diff(indptr))
+    live_out = np.bincount(src[live[indices]], minlength=live.size)
+    counters = np.asarray(engine._state[1])
+    assert np.array_equal(counters[live], live_out[live])
+
+
+# tier: the tier a cascade's second round takes, from the batch's width and
+# the fans' sizes (the first round, the hub alone, takes the batch tier)
+@pytest.mark.parametrize("tier,batch,leaves,fan_in", [
+    ("batch", 64, 32, 8), ("batch", 512, 64, 16),
+    ("plan", 64, 256, 4), ("dense", 64, 600, 2)])
+def test_batch_tier_stream_matches_dense_and_oracle(tier, batch, leaves,
+                                                    fan_in):
+    """An ``auto`` plan with both sparse tiers equals the dense-frontier
+    engine (status, counters, rounds) and the oracle in every apply, and
+    takes the case's tier.  Apply j kills fan j and deletes arcs into the
+    leaves of fans j and j + 1: the leaves' Gᵀ rows, which the second
+    round expands, hold tombstones of this batch and of the one before,
+    so a tombstone read at the wrong position shows in the counters."""
     from repro.core.stream import _batch_tier
-    g = generators.rmat(14, 1 << 17, seed=5)
+    fans = 3
+    g, hub_arcs, leaf_in = _fan_graph(fans, leaves, fan_in)
     auto = plan_stream(g, frontier="auto", instrument=True)
-    assert _batch_tier(auto.fplan, batch + 1, g.n, g.m) is not None
     dense = plan_stream(g, frontier="dense")
-    rng = np.random.default_rng(batch)
-    rounds = sparse = 0
-    for _ in range(8):
-        src, dst = _edges(auto)
-        ids = rng.choice(src.size, batch, replace=False)
-        a = auto.apply(deletions=(src[ids], dst[ids]))
-        d = dense.apply(deletions=(src[ids], dst[ids]))
-        assert a.rounds == d.rounds
+    tiers = [_batch_tier(auto.fplan, batch + 1, g.n, g.m), auto.fplan]
+    assert tiers[0] is not None
+
+    def tier_of(count, tedges):
+        return sum((count > p.cap) | (tedges > p.ecap) for p in tiers)
+
+    want = tier_of(leaves, leaves * fan_in)
+    assert want == ["batch", "plan", "dense"].index(tier)
+    assert tier_of(1, leaves) == 0
+    assert all(f.shape[1] >= batch for f in leaf_in)
+    half = (batch - 1) // 2
+    for j in range(fans):
+        # the next fan's leaves die in the next apply, over these tombstones
+        ahead = leaf_in[(j + 1) % fans]
+        dels = tuple(np.concatenate([hub_arcs[j], leaf_in[j][:, :half],
+                                     ahead[:, half + 1 - batch:]], axis=1))
+        a = auto.apply(deletions=dels)
+        d = dense.apply(deletions=dels)
+        assert a.rounds == d.rounds == 2
         assert np.array_equal(np.asarray(a.status), np.asarray(d.status))
+        assert np.array_equal(np.asarray(auto._state[1]),
+                              np.asarray(dense._state[1]))
         assert np.array_equal(np.asarray(a.status),
                               trim_oracle(*auto.snapshot().to_numpy()))
-        rounds += a.rounds
-        sparse += int(a.round_stats.total("r_sparse"))
-    assert rounds > 0 and sparse == rounds
+        _assert_counters_live_out(auto)
+        stats = a.round_stats
+        assert list(stats.per_round("r_frontier")[:2]) == [1, leaves]
+        assert list(stats.per_round("r_sparse")[:2]) == [1, int(want < 2)]
+
+
+def test_revival_after_deletions_matches_dense_and_oracle():
+    """A mixed batch whose insertion revives a dead hub rebuilds the
+    fixpoint from scratch inside the apply, over the tombstones of the
+    batch before; it equals the dense-frontier engine and the oracle, and
+    a ``full=True`` retrim reads the same state."""
+    g, hub_arcs, leaf_in = _fan_graph(1, 32, 8)
+    auto = plan_stream(g, frontier="auto")
+    dense = plan_stream(g, frontier="dense")
+    kill = tuple(np.concatenate([hub_arcs[0], leaf_in[0][:, :15]], axis=1))
+    revive = dict(deletions=tuple(leaf_in[0][:, -4:]),
+                  insertions=tuple(hub_arcs[0]))
+    for batch in (dict(deletions=kill), revive):
+        a, d = auto.apply(**batch), dense.apply(**batch)
+        assert a.dirty == d.dirty == ("insertions" in batch)
+        assert a.rounds == d.rounds
+        assert np.array_equal(np.asarray(a.status), np.asarray(d.status))
+        assert np.array_equal(np.asarray(auto._state[1]),
+                              np.asarray(dense._state[1]))
+        assert np.array_equal(np.asarray(a.status),
+                              trim_oracle(*auto.snapshot().to_numpy()))
+        _assert_counters_live_out(auto)
+    status = np.asarray(a.status)
+    assert status[hub_arcs[0][0, 0]:].all()   # the hub and its leaves
+    counters = np.asarray(auto._state[1])
+    full = auto.retrim(full=True)
+    assert np.array_equal(np.asarray(full.status).astype(bool), status)
+    assert np.array_equal(np.asarray(auto._state[1]), counters)
+    assert full.rounds == a.rounds
+
+
+def _m_gathers(jaxpr, m, loops):
+    """The gathers of all of an ``m``-element array into ``m`` elements
+    (the tombstones into Gᵀ order) in ``jaxpr``; inside ``while`` and
+    ``cond`` bodies too only if ``loops``."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            count += (eqn.invars[0].aval.size == eqn.outvars[0].aval.size
+                      == m)
+        if eqn.primitive.name in ("while", "cond") and not loops:
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    count += _m_gathers(sub, m, loops)
+    return count
+
+
+@pytest.mark.parametrize("frontier,full,revivable,top,anywhere", [
+    ("auto", False, False, 0, 1),       # a batch: only the dense round's
+    ("auto", False, True, 0, 2),        # ... and the revival rebuild's
+    ("dense", False, False, 1, 1),      # every round dense: once a step
+    ("auto", True, False, 1, 1)])       # a rebuild reads all of it first
+def test_stream_step_gathers_tombstones_where_read(frontier, full, revivable,
+                                                   top, anywhere):
+    """The m-wide gather of the tombstones into Gᵀ order runs outside the
+    fixpoint loop only where every round reads the whole mask; a batch's
+    sparse rounds read it through the permutation at their positions."""
+    import jax
+    from repro.core.common import frontier_plan
+    from repro.core.stream import _stream_runner
+    g = _random_graph(n=40, m=120, seed=15)
+    engine = plan_stream(g, frontier=frontier)
+    fn = _stream_runner("ac4", False, full=full, revivable=revivable,
+                        fplan=frontier_plan(frontier, g.n, g.m))
+    updates = engine._padded_updates(*(np.zeros(0, np.int64),) * 7)
+    jaxpr = jax.make_jaxpr(fn)(engine._transpose_arrays(),
+                               engine._overlay_arrays(), engine._state,
+                               updates).jaxpr
+    assert _m_gathers(jaxpr, g.m, loops=False) == top
+    assert _m_gathers(jaxpr, g.m, loops=True) == anywhere
 
 
 def test_plan_stream_rejects_unknown_configs():
