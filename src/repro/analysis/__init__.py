@@ -17,8 +17,8 @@ for *every* registered Pallas kernel and *every* jitted fixpoint plan:
 Both are checked statically: kernels are traced under ``jax.eval_shape``
 with their ``pallas_call`` grid/BlockSpec configuration captured
 (``analysis.capture``) and the index maps swept concretely over a pinned
-shape lattice; plans are lowered on abstract shapes through the same
-cached lowering path the dry-run uses (``launch.lowering``).
+shape lattice; plans are traced on abstract shapes through one
+process-wide jaxpr cache (``launch.lowering``).
 
 ``python -m repro.analysis.check --strict`` gates the real registry in
 CI; ``--mutants`` proves every checker fires on the deliberately broken

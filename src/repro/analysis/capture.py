@@ -11,8 +11,8 @@ zeros-stub with the declared ``out_shape`` structure so tracing proceeds;
 nothing is compiled or executed.
 
 Kernel wrappers in this repo are ``jax.jit``-wrapped; the capture helper
-traces ``fn.__wrapped__`` so a previously cached jit trace can never skip
-our recorder.
+traces with jit disabled, so a previously cached jit trace, of the
+wrapper or of a jitted helper it calls, can never skip our recorder.
 """
 from __future__ import annotations
 
@@ -119,13 +119,12 @@ def captured_calls() -> Iterator[list[PallasCapture]]:
 def capture_kernel(fn: Callable, *abstract_args, **static_kwargs) -> list[PallasCapture]:
     """Trace ``fn`` on abstract args, returning every pallas_call it makes.
 
-    ``fn`` may be a ``jax.jit`` wrapper — its ``__wrapped__`` is traced so
-    process-wide jit caches cannot bypass the recorder.  A wrapper may
-    legitimately make several pallas calls (``frontier_compact_pallas``
-    calls the ``prefix_positions`` scan first); all are returned in call
-    order.
+    ``fn`` may be a ``jax.jit`` wrapper: jit is disabled while it is
+    traced, so process-wide jit caches cannot bypass the recorder, nor
+    keep a trace made with it.  A wrapper may legitimately make several
+    pallas calls (``frontier_compact_pallas`` calls the
+    ``prefix_positions`` scan first); all are returned in call order.
     """
-    target = getattr(fn, "__wrapped__", fn)
-    with captured_calls() as records:
-        jax.eval_shape(lambda *a: target(*a, **static_kwargs), *abstract_args)
+    with captured_calls() as records, jax.disable_jit():
+        jax.eval_shape(lambda *a: fn(*a, **static_kwargs), *abstract_args)
     return records

@@ -6,10 +6,9 @@ about its grid.
 its guarantee is per lattice point, not universal (DESIGN.md §15 spells
 out the soundness caveat).  Points are chosen to exercise every
 structural regime of each kernel: single-block and multi-block grids,
-padding (shape not a block multiple), and — for flash attention — GQA
-group folding and both causal modes.  Grids stay tiny (tens to hundreds
-of programs).  The graph kernels' vertex blocks are pinned at the TPU's
-1024-element tile, the smallest block Mosaic compiles.
+padding (shape not a block multiple).  Grids stay tiny (tens to
+hundreds of programs).  The graph kernels' vertex blocks are pinned at
+the TPU's 1024-element tile, the smallest block Mosaic compiles.
 
 **Declarations.**  ``KERNEL_DECLARATIONS`` maps a kernel *body* (keyed by
 ``(module, qualname)`` — two bodies in this repo share the name
@@ -68,11 +67,6 @@ KERNEL_DECLARATIONS: dict[tuple[str, str], KernelDecl] = {
     # (vertex-blocks, update-blocks): accumulates over update blocks
     # (seed at ui == 0, deaths at ui == nu-1)
     ("repro.kernels.counter_scatter", "_counter_kernel"): _decl(1),
-    # (vertex-blocks, edge-blocks): accumulates over edge blocks
-    ("repro.kernels.segment_reduce", "_segsum_kernel"): _decl(1),
-    # (batch·heads, q-blocks, kv-blocks): streaming softmax carries
-    # m/l/acc scratch across the kv axis
-    ("repro.kernels.flash_attention", "_flash_kernel"): _decl(2, carry=True),
     # one-shot per vertex block, no accumulation
     ("repro.kernels.first_live_scan", "_scan_kernel"): _decl(),
     ("repro.kernels.frontier_expand", "_expand_kernel"): _decl(),
@@ -109,26 +103,6 @@ def _build_counter_scatter(p: dict) -> list[PallasCapture]:
         _sds((n,), "int32"), _sds((n,), "bool_"),
         _sds((b,), "int32"), _sds((b,), "int32"),
         block_v=p["block_v"], block_u=p["block_u"])
-
-
-def _build_segment_reduce(p: dict) -> list[PallasCapture]:
-    from ..kernels.segment_reduce import segment_sum_pallas
-    m, d = p["m"], p["d"]
-    return capture_kernel(
-        segment_sum_pallas,
-        _sds((m, d), "float32"), _sds((m,), "int32"),
-        num_segments=p["segs"], block_e=p["block_e"], block_n=p["block_n"])
-
-
-def _build_flash(p: dict) -> list[PallasCapture]:
-    from ..kernels.flash_attention import flash_attention
-    b, hq, hkv, sq, sk, d = (p["b"], p["hq"], p["hkv"], p["sq"], p["sk"],
-                             p["d"])
-    return capture_kernel(
-        flash_attention,
-        _sds((b, hq, sq, d), "float32"), _sds((b, hkv, sk, d), "float32"),
-        _sds((b, hkv, sk, d), "float32"),
-        causal=p["causal"], block_q=p["block_q"], block_k=p["block_k"])
 
 
 def _build_first_live(p: dict) -> list[PallasCapture]:
@@ -187,16 +161,6 @@ KERNEL_CATALOG: tuple[KernelEntry, ...] = (
         {"n": 2500, "b": 12, "block_v": 1024, "block_u": 8},   # padded
         {"n": 1024, "b": 8, "block_v": 1024, "block_u": 8},    # one block
     ), _build_counter_scatter),
-    KernelEntry("segment_reduce", (
-        {"m": 64, "d": 8, "segs": 48, "block_e": 16, "block_n": 16},
-        {"m": 40, "d": 8, "segs": 20, "block_e": 16, "block_n": 16},
-    ), _build_segment_reduce),
-    KernelEntry("flash_attention", (
-        {"b": 2, "hq": 4, "hkv": 2, "sq": 32, "sk": 32, "d": 8,
-         "block_q": 8, "block_k": 8, "causal": True},      # GQA, 8×4×4
-        {"b": 1, "hq": 2, "hkv": 2, "sq": 16, "sk": 32, "d": 8,
-         "block_q": 8, "block_k": 8, "causal": False},     # MHA, sq != sk
-    ), _build_flash),
     KernelEntry("first_live_scan", (
         {"n": 4096, "w": 16, "block_v": 1024},
         {"n": 2500, "w": 16, "block_v": 1024},             # padded

@@ -233,7 +233,7 @@ class EngineBase:
 
     def load_state(self, tree, meta) -> None:
         """Overwrite this engine's state with a checkpoint's exact arrays
-        (``tree`` from :meth:`state_dict`/``train.checkpoint.load_flat``,
+        (``tree`` from :meth:`state_dict`/``fault.checkpoint.load_flat``,
         ``meta`` from :meth:`state_meta`).  Derived caches are dropped
         and rebuilt deterministically from the restored arrays."""
         import jax.numpy as jnp

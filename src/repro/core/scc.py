@@ -208,7 +208,7 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
     driver additionally accumulates ``stats["per_worker_edges"]`` — an
     int64 ``(workers,)`` vector of traversed edges per worker summed
     over every trim pass, the quantity behind the paper's Fig. 4-style
-    load-balance comparison (``benchmarks/bench_obs.py``).
+    load-balance comparison.
 
     ``frontier`` (DESIGN.md §12) is threaded to all four engine plans.
     The driver's own dispatches are batched and therefore execute dense
@@ -241,7 +241,7 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
     generation-level driver state — labels, the pending region worklist,
     the label counter, and the stats scalars — every k completed
     generations plus once at the end, through the manifest-based
-    ``train.checkpoint`` writer (``checkpointer`` hands the IO to an
+    ``fault.checkpoint`` writer (``checkpointer`` hands the IO to an
     ``AsyncCheckpointer``).  ``resume=True`` restores the latest
     checkpoint and continues; generations are atomic and deterministic
     from (labels, regions, next_label, generation parity — the trim
@@ -361,7 +361,7 @@ def scc_decompose(graph: CSRGraph, use_trim: bool = True,
                   checkpointer=checkpointer)
 
     if resume and checkpoint_dir is not None:
-        from ..train import checkpoint as _ckpt
+        from ..fault import checkpoint as _ckpt
         last = _ckpt.latest_step(checkpoint_dir)
         if last is not None:
             tree, _, meta = _ckpt.load_flat(checkpoint_dir, last)

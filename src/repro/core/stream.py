@@ -617,7 +617,7 @@ class StreamEngine(EngineBase):
         ``full=False`` (default) returns the incrementally-maintained
         fixpoint — zero dispatches.  ``full=True`` discards the state and
         rebuilds it from scratch over the overlay in one dispatch (the
-        measured "from-scratch" baseline in ``benchmarks/bench_stream.py``).
+        from-scratch baseline an incremental apply is compared with).
         """
         import jax.numpy as jnp
         if full and self.delta.n:

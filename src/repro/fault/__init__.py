@@ -6,11 +6,11 @@ pattern (§13): a process-global, disabled-by-default plane the engines
 arm at named fault points, a seeded replayable
 :class:`~repro.fault.schedule.FaultSchedule`, bounded-backoff
 :func:`~repro.fault.retry.call_with_retries`, and engine
-checkpoint/restore glue over the ``train/checkpoint.py`` manifest writer.
+checkpoint/restore glue over the ``fault/checkpoint.py`` manifest writer.
 
 The checkpoint helpers (``save_engine``/``restore_engine``/...) are
 re-exported lazily so importing :mod:`repro.fault` from the engine hot
-path (``core/enginebase.py``) never drags in the train substrate.
+path (``core/enginebase.py``) never drags in the checkpoint writer.
 """
 from .plane import (FaultPlane, get_fault_plane, injecting_faults,
                     set_fault_plane)

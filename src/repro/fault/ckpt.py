@@ -1,4 +1,4 @@
-"""Engine checkpoint/restore over the ``train/checkpoint.py`` writer.
+"""Engine checkpoint/restore over the ``fault/checkpoint.py`` writer.
 
 The engines serialize through the ``state_dict()/state_meta()/
 load_state()`` protocol on :class:`~repro.core.enginebase.EngineBase`
@@ -7,13 +7,13 @@ graph, transpose/overlay caches, persistent fixpoint state),
 ``state_meta`` is the JSON side — engine family, the plan kwargs needed
 to re-plan in a fresh process, and the accounting counters.  This module
 is the glue: it writes both through the existing manifest-based
-``train.checkpoint`` layout (atomic tmp-dir rename, one ``.npy`` per
+``fault.checkpoint`` layout (atomic tmp-dir rename, one ``.npy`` per
 leaf), arms the ``"checkpoint-write"`` fault point, feeds the
 ``repro_checkpoint_seconds`` metric family, and rebuilds a live engine
 from a checkpoint with :func:`restore_engine`.
 
 Saves go through :func:`save_tree`, either synchronously or via a
-``train.checkpoint.AsyncCheckpointer`` (the host copy happens inline
+``fault.checkpoint.AsyncCheckpointer`` (the host copy happens inline
 either way, so the engine may mutate immediately after the call).
 """
 from __future__ import annotations
@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from . import checkpoint as _ckpt
 from .plane import get_fault_plane
 
 
@@ -45,8 +46,6 @@ def save_tree(ckpt_dir: str, step: int, tree: dict,
     rename guarantees a torn write can never shadow the previous good
     step either way.  ``checkpointer`` (an ``AsyncCheckpointer``) moves
     the disk IO off the caller's thread.  Returns ``step``."""
-    from ..train import checkpoint as _ckpt
-
     plane = get_fault_plane()
     if plane.enabled:
         plane.arm("checkpoint-write", step=step, dir=ckpt_dir)
@@ -120,8 +119,6 @@ def restore_engine(ckpt_dir: str, step: int | None = None):
     Returns ``(engine, step, tree, meta)`` — the raw tree and metadata
     ride along so callers can recover their own state saved via
     ``save_engine(extra_tree=..., extra_meta=...)``."""
-    from ..train import checkpoint as _ckpt
-
     tree, step, meta = _ckpt.load_flat(ckpt_dir, step)
     if "engine" not in meta:
         raise ValueError(f"checkpoint step {step} in {ckpt_dir!r} has no "

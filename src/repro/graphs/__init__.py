@@ -1,9 +1,7 @@
 from .generators import (BENCHMARK_GRAPHS, barabasi_albert, chain, cycle,
                          erdos_renyi, layered_dag, make, rmat, sink_heavy)
-from .sampler import NeighborSampler, SampledBlock
 
 __all__ = [
     "BENCHMARK_GRAPHS", "make", "erdos_renyi", "barabasi_albert", "rmat",
     "chain", "cycle", "layered_dag", "sink_heavy",
-    "NeighborSampler", "SampledBlock",
 ]

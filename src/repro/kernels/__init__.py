@@ -1,8 +1,5 @@
 from . import ops, ref
 from .counter_scatter import counter_scatter_pallas
 from .first_live_scan import first_live_scan
-from .flash_attention import flash_attention
-from .segment_reduce import segment_sum_pallas
 
-__all__ = ["ops", "ref", "flash_attention", "segment_sum_pallas",
-           "first_live_scan", "counter_scatter_pallas"]
+__all__ = ["ops", "ref", "first_live_scan", "counter_scatter_pallas"]

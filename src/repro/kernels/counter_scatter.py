@@ -8,13 +8,13 @@ support:
     new[v]  = counters[v] + sum over b of (delta[b] where src[b] == v)
     dead[v] = status[v] & (new[v] <= 0)
 
-``out[src[b]] += delta[b]`` has no TPU atomic; like ``segment_reduce``,
-each (vertex-block × update-block) grid cell builds the membership matrix
-``hit[b, v] = (src[b] == v)`` in VREGs and reduces it — here with an
-integer masked sum (counters are int32-exact), not the MXU — with
-*block-level update skipping*: vertex blocks that no update touches keep
-their counters verbatim (``@pl.when``), so a small delta batch costs one
-pass over the counter array and nothing else.
+``out[src[b]] += delta[b]`` has no TPU atomic, so each (vertex-block ×
+update-block) grid cell builds the membership matrix
+``hit[b, v] = (src[b] == v)`` in VREGs and reduces it with an integer
+masked sum (counters are int32-exact), with *block-level update
+skipping*: vertex blocks that no update touches keep their counters
+verbatim (``@pl.when``), so a small delta batch costs one pass over the
+counter array and nothing else.
 
 Layout: lanes = vertices within a block (×128), update batch on sublanes
 (a (B, 1) column per operand).

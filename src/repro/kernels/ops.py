@@ -23,21 +23,6 @@ from .first_live_scan import first_live_scan as _fls
 from .frontier_compact import frontier_compact_pallas as _fcp
 from .frontier_compact import sparse_expand_pallas as _sxp
 from .frontier_expand import frontier_expand as _fex
-from .flash_attention import flash_attention as _fa
-from .segment_reduce import segment_sum_pallas as _ssp
-
-
-_PERF_FLAGS_WARNED = [False]
-
-
-def _warn_perf_flags_missing():
-    if not _PERF_FLAGS_WARNED[0]:
-        _PERF_FLAGS_WARNED[0] = True
-        import warnings
-        warnings.warn(
-            "repro.launch.perf_flags is unavailable; flash_attention "
-            "falls back to default score dtype / mask handling",
-            RuntimeWarning, stacklevel=3)
 
 
 def on_tpu() -> bool:
@@ -55,41 +40,6 @@ def _select(kernel: str, use_kernel: bool | None) -> tuple[bool, bool]:
     interpret = use_kernel and not on_tpu()
     obs.note_kernel(kernel, use_kernel=use_kernel, interpret=interpret)
     return use_kernel, interpret
-
-
-def flash_attention(q, k, v, *, causal=True, sm_scale=None,
-                    use_kernel: bool | None = None, **kw):
-    """use_kernel=None: Pallas kernel on TPU; off-TPU the chunked jnp flash
-    twin (same math, streaming memory) so lowering/dry-run stays sane."""
-    use_kernel, interpret = _select("flash_attention", use_kernel)
-    if use_kernel:
-        return _fa(q, k, v, causal=causal, sm_scale=sm_scale,
-                   interpret=interpret, **kw)
-    try:
-        from ..launch.perf_flags import FLAGS
-    except ImportError as e:
-        # Only the optional module itself may be absent (stripped
-        # deployments).  A real import error *inside* perf_flags used to
-        # be swallowed here too, silently dropping the bf16-scores /
-        # additive-mask flags — re-raise those.
-        if e.name != f"{__package__.rsplit('.', 1)[0]}.launch.perf_flags":
-            raise
-        _warn_perf_flags_missing()
-    else:
-        import jax.numpy as jnp
-        kw.setdefault("score_dtype",
-                      jnp.bfloat16 if FLAGS.attn_bf16_scores else None)
-        kw.setdefault("additive_mask", FLAGS.attn_additive_mask)
-    return ref.attention_ref_chunked(q, k, v, causal=causal,
-                                     sm_scale=sm_scale, **kw)
-
-
-def segment_sum(values, seg_ids, num_segments: int,
-                use_kernel: bool | None = None, **kw):
-    use_kernel, interpret = _select("segment_sum", use_kernel)
-    if use_kernel:
-        return _ssp(values, seg_ids, num_segments, interpret=interpret, **kw)
-    return ref.segment_sum_ref(values, seg_ids, num_segments)
 
 
 def first_live_scan(flags, valid, active, use_kernel: bool | None = None,

@@ -1,94 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the ``ref.py`` contract)."""
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
-
-
-def attention_ref(q, k, v, *, causal: bool = True,
-                  sm_scale: float | None = None):
-    """Naive softmax attention with GQA; fp32 math; same signature semantics
-    as kernels.flash_attention."""
-    b, hq, sq, d = q.shape
-    _, hkv, sk, _ = k.shape
-    group = hq // hkv
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    k = jnp.repeat(k, group, axis=1)
-    v = jnp.repeat(v, group, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * sm_scale
-    if causal:
-        qpos = jnp.arange(sq)[:, None] + (sk - sq)
-        kpos = jnp.arange(sk)[None, :]
-        s = jnp.where(qpos >= kpos, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
-def attention_ref_chunked(q, k, v, *, causal: bool = True,
-                          sm_scale: float | None = None,
-                          kv_chunk: int = 8192,
-                          score_dtype=None, additive_mask: bool = False):
-    """Streaming-softmax attention with a static python loop over kv chunks
-    — the memory-sane jnp twin of the Pallas flash kernel, used when
-    lowering for the dry-run (never materializes (Sq, Sk) scores, and the
-    unrolled chunk loop keeps XLA cost_analysis exact).
-
-    GQA is computed with grouped einsums (kv never repeated)."""
-    b, hq, sq, d = q.shape
-    _, hkv, sk, _ = k.shape
-    g = hq // hkv
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    kv_chunk = min(kv_chunk, sk)
-    n_chunks = -(-sk // kv_chunk)
-    sdt = score_dtype or jnp.float32
-    big_neg = -1e30 if sdt == jnp.float32 else -3e4
-    qg = q.reshape(b, hkv, g, sq, d).astype(sdt)
-    q_pos = jnp.arange(sq) + (sk - sq)
-
-    m = jnp.full((b, hkv, g, sq), big_neg, jnp.float32)
-    den = jnp.zeros((b, hkv, g, sq), jnp.float32)
-    acc = jnp.zeros((b, hkv, g, sq, d), jnp.float32)
-    for c in range(n_chunks):
-        lo = c * kv_chunk
-        hi = min(lo + kv_chunk, sk)
-        kc = k[:, :, lo:hi].astype(sdt)
-        vc = v[:, :, lo:hi].astype(sdt)
-        s = jnp.einsum("bkgqd,bkcd->bkgqc", qg, kc,
-                       preferred_element_type=jnp.float32).astype(sdt) \
-            * sm_scale
-        if causal:
-            mask = q_pos[:, None] >= (lo + jnp.arange(hi - lo))[None, :]
-            if additive_mask:
-                bias = jnp.where(mask, 0.0, big_neg).astype(sdt)
-                s = s + bias[None, None, None]
-            else:
-                s = jnp.where(mask[None, None, None], s, big_neg)
-        # scores stay in sdt end-to-end (the Pallas kernel keeps them in
-        # VMEM; bf16 here emulates its HBM profile); stats accumulate f32
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1).astype(jnp.float32))
-        p = jnp.exp(s - m_new[..., None].astype(sdt))
-        corr = jnp.exp(m - m_new)
-        den = den * corr + jnp.sum(p, axis=-1, dtype=jnp.float32)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "bkgqc,bkcd->bkgqd", p, vc,
-            preferred_element_type=jnp.float32)
-        m = m_new
-    out = acc / jnp.maximum(den, 1e-30)[..., None]
-    return out.reshape(b, hq, sq, d).astype(q.dtype)
-
-
-def segment_sum_ref(values, seg_ids, num_segments: int):
-    """Drops out-of-range ids like the kernel (padding convention)."""
-    ok = (seg_ids >= 0) & (seg_ids < num_segments)
-    vals = jnp.where(ok[:, None], values.astype(jnp.float32), 0.0)
-    ids = jnp.where(ok, seg_ids, 0)
-    return jax.ops.segment_sum(vals, ids, num_segments=num_segments)
 
 
 def counter_scatter_ref(counters, status, upd_src, upd_delta):

@@ -1,28 +1,19 @@
-"""Shared abstract-lowering path (DESIGN.md §15).
+"""Shared abstract-lowering cache (DESIGN.md §15).
 
-Both consumers of "lower this plan on abstract shapes, never on data" go
-through this module so they share one process-wide cache:
-
-* ``launch.dryrun`` — lower + *compile* model cells to read XLA cost
-  analysis off the compiled artifact;
-* ``repro.analysis`` — trace plan jaxprs for the purity lint and the
-  instrument-diff pass.
-
-A plan the analysis pass has already traced is free for the dry-run (and
-vice versa): jitted runners are lru-cached per static configuration, so
-the cache key is the runner's identity plus the abstract input pytree.
+``repro.analysis`` traces plan jaxprs for the purity lint and the
+instrument-diff pass through :func:`trace_jaxpr`, which traces each plan
+once per process.  Jitted runners are lru-cached per static
+configuration, so the cache key is the runner's identity plus the
+abstract input pytree.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Callable
+from typing import Callable
 
 import jax
 
 _JAXPR_CACHE: dict = {}
-_COMPILE_CACHE: dict = {}
-_STATS = {"jaxpr_hits": 0, "jaxpr_misses": 0,
-          "compile_hits": 0, "compile_misses": 0}
+_STATS = {"jaxpr_hits": 0, "jaxpr_misses": 0}
 
 
 def _args_key(abstract_args: tuple) -> tuple:
@@ -49,40 +40,6 @@ def trace_jaxpr(fn: Callable, *abstract_args):
     return jaxpr
 
 
-def lower_and_compile(fn: Callable, abstract_args: tuple, *, key: Any,
-                      in_shardings=None, out_shardings=None,
-                      donate_argnums=(), mesh=None):
-    """Lower + compile ``fn`` on abstract args, cached on ``key``.
-
-    The caller supplies the key (shardings and meshes don't hash
-    usefully); the dry-run keys on its (arch, shape, mesh, variant) cell
-    coordinates.
-    """
-    if key in _COMPILE_CACHE:
-        _STATS["compile_hits"] += 1
-        return _COMPILE_CACHE[key]
-    _STATS["compile_misses"] += 1
-    kw = {}
-    if in_shardings is not None:
-        kw["in_shardings"] = in_shardings
-    if out_shardings is not None:
-        kw["out_shardings"] = out_shardings
-    if donate_argnums:
-        kw["donate_argnums"] = donate_argnums
-    ctx = mesh if mesh is not None else contextlib.nullcontext()
-    with ctx:
-        compiled = jax.jit(fn, **kw).lower(*abstract_args).compile()
-    _COMPILE_CACHE[key] = compiled
-    return compiled
-
-
 def cache_stats() -> dict:
-    return dict(_STATS, jaxprs=len(_JAXPR_CACHE),
-                compiled=len(_COMPILE_CACHE))
+    return dict(_STATS, jaxprs=len(_JAXPR_CACHE))
 
-
-def clear_caches() -> None:
-    _JAXPR_CACHE.clear()
-    _COMPILE_CACHE.clear()
-    for k in _STATS:
-        _STATS[k] = 0

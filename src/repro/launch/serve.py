@@ -1,9 +1,7 @@
-"""Serving launcher: batched prefill+decode for LM archs (smoke scale),
-batched scoring for wide-deep, and long-lived incremental graph trimming
-over a synthetic edge-update feed (the graph system this repo is about).
+"""Serving launcher: long-lived incremental graph trimming over a
+synthetic edge-update feed.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --smoke
-    PYTHONPATH=src python -m repro.launch.serve --app trim-stream --graph ER
+    PYTHONPATH=src python -m repro.launch.serve --graph ER
 """
 from __future__ import annotations
 
@@ -12,70 +10,7 @@ import signal
 import threading
 import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from .. import configs
-from ..models.recsys import WideDeep
-from ..models.transformer import LM
-
-
-def serve_lm(arch_id: str, batch: int = 4, prompt_len: int = 32,
-             gen_len: int = 16, seed: int = 0):
-    spec = configs.get(arch_id)
-    cfg = spec.make_reduced()
-    model = LM(cfg)
-    params = model.init(jax.random.PRNGKey(seed))
-    rng = np.random.default_rng(seed)
-    prompts = jnp.asarray(rng.integers(0, cfg.vocab, (batch, prompt_len)),
-                          jnp.int32)
-    # pre-allocate cache to prompt+gen and prefill
-    total = prompt_len + gen_len
-    logits, cache = jax.jit(model.prefill)(params, prompts)
-    # pad cache to total length
-    k, v = cache
-    pad = total - prompt_len
-    k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    cache = (k, v)
-    decode = jax.jit(model.decode_step, donate_argnums=(1,))
-    tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-    out = [tok]
-    t0 = time.perf_counter()
-    for i in range(gen_len):
-        pos = jnp.array(prompt_len + i, jnp.int32)
-        logits, cache = decode(params, cache, tok, pos)
-        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        out.append(tok)
-    dt = time.perf_counter() - t0
-    toks = jnp.concatenate(out, axis=1)
-    print(f"[serve] {arch_id}: generated {gen_len} tokens x{batch} "
-          f"in {dt*1e3:.1f} ms ({batch*gen_len/dt:.0f} tok/s)")
-    return np.asarray(toks)
-
-
-def serve_recsys(batch: int = 64, seed: int = 0):
-    spec = configs.get("wide-deep")
-    cfg = spec.make_reduced()
-    model = WideDeep(cfg)
-    params = model.init(jax.random.PRNGKey(seed))
-    rng = np.random.default_rng(seed)
-    b = {"dense": jnp.asarray(rng.normal(size=(batch, cfg.n_dense)),
-                              jnp.float32),
-         "sparse_ids": jnp.asarray(
-             rng.integers(0, min(cfg.vocab_sizes),
-                          (batch, cfg.n_sparse, cfg.ids_per_field)),
-             jnp.int32)}
-    fwd = jax.jit(model.forward)
-    scores = fwd(params, b)
-    t0 = time.perf_counter()
-    for _ in range(10):
-        scores = fwd(params, b)
-    scores.block_until_ready()
-    dt = (time.perf_counter() - t0) / 10
-    print(f"[serve] wide-deep: batch {batch} in {dt*1e6:.0f} us/req-batch")
-    return np.asarray(scores)
 
 
 # serving-scale graph families: small enough for a 1-core container to
@@ -228,7 +163,7 @@ def serve_trim_stream(graph: str = "ER", ticks: int = 20, batch: int = 256,
         dst = indices_h.astype(np.int64)
         engine = None
         if checkpoint_dir is not None:
-            from ..train import checkpoint as _ckpt
+            from ..fault import checkpoint as _ckpt
             checkpointer = _ckpt.AsyncCheckpointer(checkpoint_dir)
             if _ckpt.latest_step(checkpoint_dir) is not None:
                 (engine, alive, pending, rng, tick,
@@ -437,19 +372,13 @@ def serve_trim_stream(graph: str = "ER", ticks: int = 20, batch: int = 256,
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--app", default="model",
-                    choices=("model", "trim-stream"))
-    ap.add_argument("--arch")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--graph", default="ER", choices=sorted(_STREAM_GRAPHS))
     ap.add_argument("--ticks", type=int, default=20)
     ap.add_argument("--update-batch", type=int, default=256)
     ap.add_argument("--instrument", action="store_true",
-                    help="device-resident round telemetry (trim-stream)")
+                    help="device-resident round telemetry")
     ap.add_argument("--trace", metavar="PATH",
-                    help="write a chrome://tracing timeline (trim-stream)")
+                    help="write a chrome://tracing timeline")
     ap.add_argument("--metrics-port", type=int, default=None,
                     metavar="PORT",
                     help="serve /metrics + /healthz on this port (0 = any "
@@ -466,7 +395,7 @@ def main():
                          "(with --metrics-port)")
     ap.add_argument("--checkpoint-dir", metavar="DIR",
                     help="checkpoint engine + feed state here and resume "
-                         "from the latest step on startup (trim-stream)")
+                         "from the latest step on startup")
     ap.add_argument("--checkpoint-every", type=int, default=5,
                     metavar="TICKS",
                     help="ticks between checkpoints (with "
@@ -482,31 +411,15 @@ def main():
     args = ap.parse_args()
     from .compile_cache import enable_compile_cache
     enable_compile_cache()
-    if args.app == "trim-stream":
-        serve_trim_stream(args.graph, ticks=args.ticks,
-                          batch=args.update_batch,
-                          instrument=args.instrument, trace=args.trace,
-                          metrics_port=args.metrics_port,
-                          slo_ms=args.slo_ms,
-                          metrics_hold=args.metrics_hold,
-                          metrics_json=args.metrics_json,
-                          checkpoint_dir=args.checkpoint_dir,
-                          checkpoint_every=args.checkpoint_every,
-                          fault_seed=args.fault_seed,
-                          fault_rate=args.fault_rate,
-                          retries=args.retries)
-        return
-    if args.arch is None:
-        ap.error("--arch is required for --app model")
-    if not args.smoke:
-        raise SystemExit("full-scale serving requires TPUs; use --smoke")
-    spec = configs.get(args.arch)
-    if spec.family == "lm":
-        serve_lm(args.arch, batch=args.batch, gen_len=args.gen_len)
-    elif spec.family == "recsys":
-        serve_recsys(batch=args.batch)
-    else:
-        raise SystemExit("serving applies to lm/recsys archs")
+    serve_trim_stream(args.graph, ticks=args.ticks, batch=args.update_batch,
+                      instrument=args.instrument, trace=args.trace,
+                      metrics_port=args.metrics_port, slo_ms=args.slo_ms,
+                      metrics_hold=args.metrics_hold,
+                      metrics_json=args.metrics_json,
+                      checkpoint_dir=args.checkpoint_dir,
+                      checkpoint_every=args.checkpoint_every,
+                      fault_seed=args.fault_seed, fault_rate=args.fault_rate,
+                      retries=args.retries)
 
 
 if __name__ == "__main__":

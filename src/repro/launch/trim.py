@@ -73,7 +73,7 @@ def run_scc(graph_name: str, method: str, backend: str = "dense",
     g = make(graph_name)
     if checkpoint_dir is not None:
         from .. import fault as flt
-        from ..train.checkpoint import AsyncCheckpointer
+        from ..fault.checkpoint import AsyncCheckpointer
         checkpointer = AsyncCheckpointer(checkpoint_dir)
         t0 = time.time()
         try:
@@ -271,8 +271,7 @@ def main():
     args = ap.parse_args()
     if args.app == "check":
         # the static-analysis plane: no graph, no engines, no device work —
-        # delegate to the repro.analysis.check CLI (shared lowering cache
-        # means a later --dryrun in the same process reuses its jaxprs)
+        # delegate to the repro.analysis.check CLI
         if args.fault_seed is not None or args.checkpoint_dir:
             ap.error("--app check is static analysis; fault injection and "
                      "checkpoints don't apply")

@@ -4,8 +4,7 @@
 ``jit(f).lower(*args).compile().cost_analysis()`` yields estimated
 FLOPs and bytes accessed for the whole computation.  This module
 normalizes that across jax versions (dict vs list-of-dicts vs None) and
-publishes it as the ``repro_plan_cost_*`` gauge families that
-``benchmarks/roofline.py`` consumes instead of hand-rolled estimates.
+publishes it as the ``repro_plan_cost_*`` gauge families.
 
 Cost analysis is only extracted on *compile* dispatches — the lowering
 needed to reach the executable retraces the function, so doing it per

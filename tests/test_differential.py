@@ -13,20 +13,32 @@ The fixtures are the shapes that break trimming code in practice: the
 n = 0 graph (degenerate dispatch paths), an edgeless graph (everything is
 the zero bucket), a single self-loop (a cycle trimming must never
 remove), a long chain (α = n, the AC-3 worst case crossing every block
-boundary), a star (one frontier round killing almost everything), and two
+boundary), a star (one frontier round killing almost everything), two
 2-cycles bridged by a dead tail (live SCCs upstream of trimmable mass —
-the trim-2 shape).
+the trim-2 shape), and the benchmark graphs' shapes at small scale: a
+Kronecker graph with scrambled labels (duplicate arcs, self-loops, hub
+skew) and uniform random arcs of degree 16.
 """
 import numpy as np
 import pytest
 
 from repro.core import CSRGraph, plan, plan_peel, plan_stream, trim_oracle
 from repro.core.reach import plan_reach
+from repro.graphs import generators
 
 
 def _graph(n, src=(), dst=()):
     return CSRGraph.from_edges(n, np.asarray(src, np.int64),
                                np.asarray(dst, np.int64))
+
+
+def _kron(scale, m, seed=1):
+    """R-MAT with Graph500's A/B/C, labels scrambled by a seeded
+    permutation (duplicate arcs and self-loops kept)."""
+    ip, ix = generators.rmat(scale, m, seed=seed).to_numpy()
+    perm = np.random.default_rng(seed).permutation(len(ip) - 1)
+    src = perm[np.repeat(np.arange(len(ip) - 1), np.diff(ip))]
+    return _graph(len(ip) - 1, src, perm[ix])
 
 
 FIXTURES = {
@@ -38,6 +50,8 @@ FIXTURES = {
     # 0<->1 -> 2<->3 -> 4 -> 5   (two 2-cycles bridged by a dead tail)
     "bridged_2cycles": _graph(6, [0, 1, 1, 2, 3, 3, 4],
                               [1, 0, 2, 3, 2, 4, 5]),
+    "kron": _kron(9, 16 * 512),
+    "urand": generators.erdos_renyi(512, 16 * 512, seed=1),
 }
 METHODS = ("ac3", "ac4", "ac4*", "ac6")
 BACKENDS = ("dense", "windowed", "sharded")

@@ -18,9 +18,11 @@ Three contracts, asserted across all four engine families:
 """
 import os
 import signal
+import tempfile
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -29,7 +31,7 @@ from repro.core import plan, plan_peel, plan_stream
 from repro.core.reach import plan_reach
 from repro.core.scc import scc_decompose
 from repro.graphs import generators
-from repro.train import checkpoint as ckpt_lib
+from repro.fault import checkpoint as ckpt_lib
 
 
 def _er(n=64, m=256, seed=3):
@@ -250,6 +252,31 @@ def test_sharded_trim_not_checkpointable():
 
 # -- contract 3: durable checkpoint writes ------------------------------------
 
+def test_checkpoint_roundtrip_and_prune():
+    with tempfile.TemporaryDirectory() as d:
+        tree = {"a": jnp.arange(6.0).reshape(2, 3),
+                "b": {"c": jnp.ones((4,), jnp.int32)}}
+        for step in (1, 2, 3, 4):
+            ckpt_lib.save(d, step, tree, metadata={"s": step})
+        ckpt_lib.prune(d, keep=2)
+        assert ckpt_lib.latest_step(d) == 4
+        got, step, meta = ckpt_lib.restore(d, tree)
+        assert step == 4 and meta["s"] == 4
+        np.testing.assert_array_equal(got["a"], tree["a"])
+        assert len([x for x in os.listdir(d) if x.startswith("step_")]) == 2
+
+
+def test_async_checkpointer():
+    with tempfile.TemporaryDirectory() as d:
+        ac = ckpt_lib.AsyncCheckpointer(d, keep=2)
+        tree = {"w": jnp.ones((8, 8))}
+        ac.save(1, tree)
+        ac.save(2, tree)
+        ac.wait()
+        assert ckpt_lib.latest_step(d) == 2
+        ac.close()
+
+
 def test_checkpoint_write_fault_preserves_latest(tmp_path):
     g = _er()
     e = plan(g, method="ac4")
@@ -451,8 +478,8 @@ def test_serve_sigkill_subprocess_resumes_bit_identical(tmp_path):
                                      "src")
 
     def cmd(d, ticks):
-        return [sys.executable, "-m", "repro.launch.serve", "--app",
-                "trim-stream", "--graph", "chain", "--ticks", str(ticks),
+        return [sys.executable, "-m", "repro.launch.serve",
+                "--graph", "chain", "--ticks", str(ticks),
                 "--update-batch", "32", "--checkpoint-dir", d,
                 "--checkpoint-every", "2"]
 

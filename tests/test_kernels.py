@@ -9,59 +9,12 @@ from repro.kernels import ref
 from repro.kernels.bucket_peel import bucket_peel_pallas
 from repro.kernels.counter_scatter import counter_scatter_pallas
 from repro.kernels.first_live_scan import first_live_scan
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.frontier_compact import (frontier_compact_pallas,
                                             prefix_positions,
                                             sparse_expand_pallas)
 from repro.kernels.frontier_expand import frontier_expand
-from repro.kernels.segment_reduce import segment_sum_pallas
 
 RNG = np.random.default_rng(0)
-
-
-@pytest.mark.parametrize(
-    "b,hq,hkv,sq,sk,d,causal,dtype",
-    [
-        (1, 2, 2, 128, 128, 64, True, jnp.float32),
-        (2, 4, 2, 256, 256, 64, True, jnp.float32),
-        (1, 8, 2, 128, 256, 128, False, jnp.float32),
-        (1, 2, 1, 256, 512, 64, True, jnp.float32),   # sk > sq (prefix)
-        (1, 4, 4, 128, 128, 64, True, jnp.bfloat16),
-    ])
-def test_flash_attention(b, hq, hkv, sq, sk, d, causal, dtype):
-    q = jnp.asarray(RNG.normal(size=(b, hq, sq, d)), dtype)
-    k = jnp.asarray(RNG.normal(size=(b, hkv, sk, d)), dtype)
-    v = jnp.asarray(RNG.normal(size=(b, hkv, sk, d)), dtype)
-    got = flash_attention(q, k, v, causal=causal, interpret=True)
-    want = ref.attention_ref(q, k, v, causal=causal)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=tol, rtol=tol)
-
-
-def test_attention_chunked_matches_ref():
-    """The jnp flash twin used for dry-run lowering is exact too."""
-    q = jnp.asarray(RNG.normal(size=(2, 4, 64, 32)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(2, 2, 192, 32)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(2, 2, 192, 32)), jnp.float32)
-    got = ref.attention_ref_chunked(q, k, v, causal=True, kv_chunk=64)
-    want = ref.attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("m,d,n,be,bn", [
-    (1000, 32, 177, 256, 128),
-    (512, 8, 64, 128, 64),
-    (77, 16, 33, 512, 512),      # smaller than one block
-])
-def test_segment_sum(m, d, n, be, bn):
-    vals = jnp.asarray(RNG.normal(size=(m, d)), jnp.float32)
-    ids = jnp.asarray(RNG.integers(0, n, m), jnp.int32)
-    got = segment_sum_pallas(vals, ids, n, block_e=be, block_n=bn,
-                             interpret=True)
-    want = ref.segment_sum_ref(vals, ids, n)
-    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
 
 
 @pytest.mark.parametrize("n,W,bv", [(333, 16, 128), (64, 8, 64),
@@ -292,59 +245,3 @@ def test_frontier_compact_no_retrace():
                  RNG.random(64) < 0.5):
         jitted(jnp.asarray(mask)).block_until_ready()
     assert traces == 1
-
-
-# -- independent numpy oracles (DESIGN.md §15) ---------------------------------
-# The cells above compare the Pallas kernels against the repo's own jnp
-# references; these two recompute the math in plain numpy (float64) so a
-# shared bug in kernels/ and ref.py cannot cancel out.
-
-def _np_attention(q, k, v, causal):
-    """Dense softmax attention with GQA, written against the paper-standard
-    definition in float64 numpy — no jax anywhere."""
-    q = np.asarray(q, np.float64)
-    k = np.asarray(k, np.float64)
-    v = np.asarray(v, np.float64)
-    b, hq, sq, d = q.shape
-    _, hkv, sk, _ = k.shape
-    k = np.repeat(k, hq // hkv, axis=1)
-    v = np.repeat(v, hq // hkv, axis=1)
-    s = np.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
-    if causal:
-        qpos = np.arange(sq)[:, None] + (sk - sq)
-        keep = qpos >= np.arange(sk)[None, :]
-        s = np.where(keep, s, -np.inf)
-    s -= s.max(axis=-1, keepdims=True)
-    p = np.exp(s)
-    p /= p.sum(axis=-1, keepdims=True)
-    return np.einsum("bhqk,bhkd->bhqd", p, v)
-
-
-@pytest.mark.parametrize("b,hq,hkv,sq,sk,causal", [
-    (1, 2, 2, 128, 128, True),
-    (2, 4, 2, 128, 256, True),    # GQA + prefix (sk > sq)
-    (1, 2, 1, 128, 128, False),
-])
-def test_flash_attention_numpy_oracle(b, hq, hkv, sq, sk, causal):
-    d = 64
-    q = jnp.asarray(RNG.normal(size=(b, hq, sq, d)), jnp.float32)
-    k = jnp.asarray(RNG.normal(size=(b, hkv, sk, d)), jnp.float32)
-    v = jnp.asarray(RNG.normal(size=(b, hkv, sk, d)), jnp.float32)
-    got = flash_attention(q, k, v, causal=causal, interpret=True)
-    want = _np_attention(q, k, v, causal)
-    np.testing.assert_allclose(np.asarray(got, np.float64), want,
-                               atol=3e-5, rtol=3e-5)
-
-
-@pytest.mark.parametrize("m,d,n", [(513, 16, 37), (128, 4, 200)])
-def test_segment_sum_numpy_oracle(m, d, n):
-    vals = RNG.normal(size=(m, d)).astype(np.float32)
-    # out-of-range ids (the padding convention) must be dropped
-    ids = RNG.integers(-2, n + 2, m).astype(np.int32)
-    want = np.zeros((n, d), np.float64)
-    ok = (ids >= 0) & (ids < n)
-    np.add.at(want, ids[ok], vals[ok].astype(np.float64))
-    got = segment_sum_pallas(jnp.asarray(vals), jnp.asarray(ids), n,
-                             block_e=128, block_n=64, interpret=True)
-    np.testing.assert_allclose(np.asarray(got, np.float64), want,
-                               atol=1e-4, rtol=1e-4)

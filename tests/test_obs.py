@@ -1,25 +1,30 @@
 """Observability subsystem coverage (DESIGN.md §11): the zero-overhead
 invariant (``instrument=False`` is bit-identical, no extra dispatches, no
-retrace), device round-stats parity against a host oracle on all six
-graph families, span recording with compile attribution, exporter
-round-trips, and the bench regression gate's comparison rules."""
-import copy
+retrace), per-round telemetry of all four trimming methods against host
+oracles on eight graph families, span recording with compile
+attribution, and exporter round-trips."""
 import json
 import os
-import sys
+from functools import partial
 
 import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import plan, plan_peel, plan_reach, plan_stream
+from repro.core import CSRGraph, plan, plan_peel, plan_reach, plan_stream
 from repro.core.ref import trim_oracle
 from repro.core.scc import scc_decompose, same_partition, tarjan_oracle
 from repro.graphs import generators
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                "benchmarks"))
-from check_regression import Verdict, compare_docs  # noqa: E402
+
+def _kron(scale, m, seed=1):
+    """R-MAT with Graph500's A/B/C (duplicate arcs and self-loops kept)
+    and vertex labels scrambled by a seeded permutation, the shape of the
+    ``kron`` benchmark graphs at small scale."""
+    ip, ix = generators.rmat(scale, m, seed=seed).to_numpy()
+    perm = np.random.default_rng(seed).permutation(len(ip) - 1)
+    src = perm[np.repeat(np.arange(len(ip) - 1), np.diff(ip))]
+    return CSRGraph.from_edges(len(ip) - 1, src, perm[ix])
 
 
 def _families():
@@ -30,6 +35,9 @@ def _families():
         "chain": generators.chain(50),
         "layered": generators.layered_dag(200, 11, 4, seed=1),
         "sink_heavy": generators.sink_heavy(200, 800, 0.9, seed=1),
+        "kron": _kron(9, 16 * 512),
+        # uniform random arcs, degree 16 (the ``urand`` benchmark shape)
+        "urand": generators.erdos_renyi(512, 16 * 512, seed=1),
     }
 
 
@@ -98,25 +106,98 @@ def test_instrument_off_bit_identical_no_retrace_no_extra_dispatch():
         assert np.array_equal(np.asarray(r0.status), np.asarray(r2.status))
 
 
+def _host_scan(live, indptr, indices, start, scanning):
+    """Per scanning vertex, the first position at or after ``start`` whose
+    successor is in the round-start snapshot ``live``: (found, position
+    (the degree when exhausted), arcs examined including the hit)."""
+    deg = np.diff(indptr)
+    found = np.zeros(len(deg), bool)
+    pos = deg.copy()
+    examined = np.zeros(len(deg), np.int64)
+    for v in np.nonzero(scanning)[0]:
+        row = indices[indptr[v]:indptr[v + 1]]
+        p = first = min(start[v], deg[v])
+        while p < deg[v] and not live[row[p]]:
+            p += 1
+        found[v] = p < deg[v]
+        pos[v] = p
+        examined[v] = p - first + found[v]
+    return found, pos, examined
+
+
+def host_ac3_rounds(indptr, indices):
+    """Host oracle for AC-3's per-round telemetry: every live vertex
+    re-scans from its pointer (its last support, not past it); a vertex
+    with no successor live at the round's start dies.  The round that
+    kills nothing is the last, and is counted."""
+    n = len(indptr) - 1
+    live = np.ones(n, bool)
+    ptr = np.zeros(n, np.int64)
+    r_frontier, r_edges = [], []
+    while True:
+        found, pos, examined = _host_scan(live, indptr, indices, ptr, live)
+        dead = live & ~found
+        r_frontier.append(int(dead.sum()))
+        r_edges.append(int(examined.sum()))
+        ptr = np.where(live, pos, ptr)
+        live &= found
+        if not dead.any():
+            return np.asarray(r_frontier), np.asarray(r_edges)
+
+
+def host_ac6_rounds(indptr, indices):
+    """Host oracle for AC-6's per-round telemetry: only vertices whose
+    support died scan, strictly past it (every vertex in round 0, from
+    position 0); the rounds run until no live vertex has a dead
+    support."""
+    n = len(indptr) - 1
+    deg = np.diff(indptr)
+    live = np.ones(n, bool)
+    ptr = np.full(n, -1, np.int64)
+    affected = live.copy()
+    r_frontier, r_edges = [], []
+    while affected.any():
+        found, pos, examined = _host_scan(live, indptr, indices, ptr + 1,
+                                          affected)
+        dead = affected & ~found
+        r_frontier.append(int(dead.sum()))
+        r_edges.append(int(examined.sum()))
+        ptr = np.where(affected, pos, ptr)
+        live &= ~dead
+        held = np.nonzero(live & (deg > 0))[0]
+        affected = np.zeros(n, bool)
+        affected[held] = ~live[indices[indptr[held] + ptr[held]]]
+    return np.asarray(r_frontier), np.asarray(r_edges)
+
+
+HOST_ROUNDS = {
+    "ac3": host_ac3_rounds,
+    "ac4": partial(host_ac4_rounds, count_init_scan=True),
+    "ac4*": partial(host_ac4_rounds, count_init_scan=False),
+    "ac6": host_ac6_rounds,
+}
+
+
 # -- device round stats vs host oracle ---------------------------------------
 
-@pytest.mark.parametrize("family", ["ER", "BA", "RMAT", "chain",
-                                    "layered", "sink_heavy"])
-def test_ac4_round_stats_match_host_oracle(family):
+@pytest.mark.parametrize("method", list(HOST_ROUNDS))
+@pytest.mark.parametrize("family", ["ER", "BA", "RMAT", "chain", "layered",
+                                    "sink_heavy", "kron", "urand"])
+def test_ac4_round_stats_match_host_oracle(family, method):
     g = _families()[family]
     indptr, indices = g.to_numpy()
-    for method, init_scan in (("ac4", True), ("ac4*", False)):
-        rs = plan(g, method=method, instrument=True).run().round_stats
-        hf, he = host_ac4_rounds(indptr, indices, count_init_scan=init_scan)
-        pf, pe = rs.per_round("r_frontier"), rs.per_round("r_edges")
-        r = len(hf)
-        assert np.array_equal(pf[:r], hf), (family, method)
-        assert np.array_equal(pe[:r], he), (family, method)
-        assert pf[r:].sum() == 0 and pe[r:].sum() == 0, (family, method)
-        # status agrees with the trim oracle while we're here
-        status = np.asarray(plan(g, method=method).run().status)
-        assert np.array_equal(status.astype(bool),
-                              trim_oracle(indptr, indices))
+    rs = plan(g, method=method, instrument=True).run().round_stats
+    hf, he = HOST_ROUNDS[method](indptr, indices)
+    pf, pe = rs.per_round("r_frontier"), rs.per_round("r_edges")
+    r = len(hf)
+    assert np.array_equal(pf[:r], hf), (family, method)
+    assert np.array_equal(pe[:r], he), (family, method)
+    assert pf[r:].sum() == 0 and pe[r:].sum() == 0, (family, method)
+    if method == "ac6":                  # each arc examined at most once
+        assert he.sum() <= g.m, family
+    # status agrees with the trim oracle while we're here
+    status = np.asarray(plan(g, method=method).run().status)
+    assert np.array_equal(status.astype(bool), trim_oracle(indptr, indices))
 
 
 def test_round_totals_agree_with_per_worker_counters():
@@ -265,147 +346,6 @@ def test_round_capacity():
     assert obs.round_capacity(100, max_rounds=3) == 4
     with pytest.raises(ValueError):
         obs.round_capacity(100, max_rounds=0)
-
-
-# -- the regression gate -----------------------------------------------------
-
-def _doc(**over):
-    d = {
-        "schema": 3, "bench": "obs", "smoke": True,
-        "env": {"jax_version": "0.4.37", "backend": "cpu",
-                "device_kind": "cpu", "device_count": 1,
-                "python": "3.11", "commit": "abc"},
-        "families": {"ER": {"n": 100, "m": 200, "edges_total": 42,
-                            "x_ms": 10.0, "ordering_ok": True}},
-        "ordering_ok": True,
-    }
-    d.update(over)
-    return d
-
-
-def test_compare_docs_ok_and_timing_tolerance():
-    assert compare_docs(_doc(), _doc()) == (Verdict.OK, [])
-    slow = _doc()
-    slow["families"]["ER"]["x_ms"] = 15.0      # within 2x
-    assert compare_docs(_doc(), slow)[0] == Verdict.OK
-    slow["families"]["ER"]["x_ms"] = 25.0      # beyond 2x
-    assert compare_docs(_doc(), slow)[0] == Verdict.FAIL
-    # tolerance applies to slowdowns only
-    fast = _doc()
-    fast["families"]["ER"]["x_ms"] = 0.1
-    assert compare_docs(_doc(), fast)[0] == Verdict.OK
-
-
-def test_compare_docs_deterministic_keys_exact():
-    drift = _doc()
-    drift["families"]["ER"]["edges_total"] = 43
-    verdict, msgs = compare_docs(_doc(), drift)
-    assert verdict == Verdict.FAIL and "edges_total" in msgs[0]
-
-
-def test_compare_docs_refuses_env_mismatch():
-    other = _doc()
-    other["env"] = dict(other["env"], backend="tpu")
-    verdict, msgs = compare_docs(_doc(), other)
-    assert verdict == Verdict.REFUSED
-    assert any("backend" in m for m in msgs)
-    # ...unless a scale-free claim is broken: that is a FAIL even
-    # cross-environment
-    other = copy.deepcopy(other)
-    other["families"]["ER"]["ordering_ok"] = False
-    assert compare_docs(_doc(), other)[0] == Verdict.FAIL
-
-
-def test_compare_docs_workload_mismatch_checks_scale_free_only():
-    small = _doc()
-    small["families"]["ER"]["n"] = 50
-    small["families"]["ER"]["edges_total"] = 7   # different size: ignored
-    verdict, _ = compare_docs(_doc(), small)
-    assert verdict == Verdict.OK
-    small = copy.deepcopy(small)
-    small["ordering_ok"] = False
-    assert compare_docs(_doc(), small)[0] == Verdict.FAIL
-
-
-def test_compare_docs_missing_family_fails():
-    """A baseline family dropped from the fresh run is a hard FAIL at any
-    workload — never a silent scale-free pass."""
-    gone = _doc()
-    del gone["families"]["ER"]
-    gone["smoke"] = False                      # workload differs too
-    verdict, msgs = compare_docs(_doc(), gone)
-    assert verdict == Verdict.FAIL
-    assert "missing" in msgs[0] and "ER" in msgs[0]
-    # extra fresh families are fine: the workload merely differs
-    extra = _doc()
-    extra["families"]["BA"] = dict(extra["families"]["ER"])
-    assert compare_docs(_doc(), extra)[0] == Verdict.OK
-
-
-def test_compare_docs_summary_names_regressed_families():
-    slow = _doc()
-    slow["families"]["ER"]["x_ms"] = 25.0
-    verdict, msgs = compare_docs(_doc(), slow)
-    assert verdict == Verdict.FAIL
-    assert msgs[-1] == "regressed families: ER"
-
-
-def test_compare_docs_rate_keys_gate_drops_only():
-    """speedup_*/_per_sec are wall-clock-derived, higher-is-better: a
-    big jump is the win being measured, a big drop is the regression."""
-    base = _doc()
-    base["families"]["ER"].update(speedup_host=2.0, upd_per_sec=1000.0)
-    better = _doc()
-    better["families"]["ER"].update(speedup_host=9.0, upd_per_sec=9000.0)
-    assert compare_docs(base, better)[0] == Verdict.OK
-    worse = _doc()
-    worse["families"]["ER"].update(speedup_host=0.5, upd_per_sec=100.0)
-    verdict, msgs = compare_docs(base, worse)
-    assert verdict == Verdict.FAIL
-    assert any("speedup_host" in m for m in msgs)
-    assert any("upd_per_sec" in m for m in msgs)
-
-
-def test_compare_docs_string_keys_exact():
-    """String keys (frontier_path_taken) are deterministic: drift fails."""
-    base = _doc()
-    base["families"]["ER"]["frontier_path_taken"] = "sparse"
-    flipped = _doc()
-    flipped["families"]["ER"]["frontier_path_taken"] = "dense"
-    verdict, msgs = compare_docs(base, flipped)
-    assert verdict == Verdict.FAIL
-    assert any("frontier_path_taken" in m for m in msgs)
-    same = _doc()
-    same["families"]["ER"]["frontier_path_taken"] = "sparse"
-    assert compare_docs(base, same)[0] == Verdict.OK
-
-
-def test_compare_docs_rejects_malformed():
-    v1 = _doc()
-    del v1["schema"]
-    verdict, msgs = compare_docs(v1, _doc())
-    assert verdict == Verdict.FAIL and "schema" in msgs[0]
-    wrong = _doc(bench="peel")
-    assert compare_docs(_doc(), wrong)[0] == Verdict.FAIL
-    stale = _doc(schema=2)                     # pre-telemetry-gate layout
-    verdict, msgs = compare_docs(stale, _doc())
-    assert verdict == Verdict.FAIL and "schema" in msgs[0]
-
-
-def test_compare_docs_gates_telemetry_keys_exactly():
-    """rounds / edges_total / max_per_worker / imbalance are deterministic
-    device telemetry: any drift on a matching workload is a FAIL, not a
-    tolerance-band pass (schema 3 contract)."""
-    for key, drifted in (("rounds", 9), ("edges_total", 43),
-                         ("max_per_worker", 5), ("imbalance", 1.5)):
-        base = _doc()
-        base["families"]["ER"].update(rounds=8, edges_total=42,
-                                      max_per_worker=4, imbalance=1.25)
-        moved = copy.deepcopy(base)
-        moved["families"]["ER"][key] = drifted
-        assert compare_docs(base, base)[0] == Verdict.OK
-        verdict, msgs = compare_docs(base, moved)
-        assert verdict == Verdict.FAIL and any(key in m for m in msgs), key
 
 
 # -- MetricsPlane: labeled metrics, exposition, snapshot ----------------------

@@ -84,8 +84,7 @@ PALLAS_NAMES = {"first_live_scan": "first_live_scan",
                 "counter_scatter": "counter_scatter",
                 "frontier_compact": "prefix_positions",
                 "sparse_expand": "prefix_positions",
-                "prefix_positions": "prefix_positions",
-                "segment_reduce": "segment_reduce"}
+                "prefix_positions": "prefix_positions"}
 
 
 @pytest.mark.parametrize("kernel", [
@@ -112,8 +111,7 @@ def test_graph_kernel_compiles_for_v5e(one_chip, kernel):
 
 @pytest.mark.parametrize("kernel", sorted(PALLAS_NAMES))
 def test_graph_kernel_names_its_pallas_call(kernel):
-    """Every graph kernel's pallas_call carries its ``name=``, also the
-    GNN segment sum that no chip path compiles (captured abstractly)."""
+    """Every graph kernel's pallas_call carries its ``name=``."""
     from repro.analysis.catalog import KERNEL_CATALOG
     entry = next(e for e in KERNEL_CATALOG if e.name == kernel)
     calls = entry.build(entry.points[0])
