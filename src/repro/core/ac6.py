@@ -156,9 +156,10 @@ def ac6_kernel(indptr, indices, worker_ids, workers: int, active=None, *,
             new_st_rows = st2[rowc] & ~(scan2 & ~found2)
             new_status = st2.at[cids].set(new_st_rows,
                                           mode="drop").reshape(-1)
-            pw = (zero_pw.at[jnp.where(
-                scan, wk2[rowc].reshape(-1),
-                workers)].add(probes, mode="drop") if counters else zero_pw)
+            # probes is 0 off ``scan``: pad slots and repeated rows add 0
+            pw = (per_worker_add(zero_pw, probes, wk2[rowc].reshape(-1),
+                                 workers)
+                  if counters else zero_pw)
             ps = jnp.sum(probes) if instrument else jnp.int32(0)
             return new_status, ptr, supp, pw, ps
 

@@ -252,11 +252,18 @@ def resolve_probe(kind: str = "dense", window: int = 16,
 
 
 def per_worker_add(acc, values, worker_ids, workers: int):
-    """acc[p] += sum of values over vertices owned by worker p."""
-    return acc + jax.ops.segment_sum(values.astype(jnp.int32), worker_ids,
-                                     num_segments=workers)
+    """acc[p] += sum of values over vertices owned by worker p.
+
+    One masked int32 reduction per worker, stacked: no scatter into the
+    (workers,) slots, and exact for any ``worker_ids`` (integer sums do
+    not depend on order; ids outside [0, workers) count nowhere)."""
+    values = values.astype(jnp.int32)
+    return acc + jnp.stack([
+        jnp.sum(jnp.where(worker_ids == p, values, 0), dtype=jnp.int32)
+        for p in range(workers)])
 
 
 def worker_counts(mask, worker_ids, workers: int):
-    return jax.ops.segment_sum(mask.astype(jnp.int32), worker_ids,
-                               num_segments=workers)
+    """(workers,) int32: members of ``mask`` owned by each worker."""
+    return per_worker_add(jnp.zeros((workers,), jnp.int32), mask,
+                          worker_ids, workers)

@@ -6,14 +6,24 @@ engine's own accounting (bumped only inside traced functions); the jit
 cache is process-wide, so tests that assert an exact count use graph
 shapes no other test produces.
 """
+import collections
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (CSRGraph, available_methods, get_kernel,
-                        peeling_alpha_oracle, plan, trim, trim_oracle)
+                        peeling_alpha_oracle, plan, trim, trim_oracle,
+                        worker_of)
+from repro.core.ac6 import ac6_kernel
+from repro.core.common import frontier_plan
 from repro.core.engine import BACKENDS
 from repro.core.scc import same_partition, scc_decompose, tarjan_oracle
 from repro.graphs import barabasi_albert
+
+from host_rounds import HOST_ROUNDS
 
 METHODS = ("ac3", "ac4", "ac4*", "ac6")
 
@@ -116,6 +126,64 @@ def test_counters_false_batch():
         assert res.per_worker_edges is None
         assert (np.asarray(res.status).astype(bool)
                 == trim_oracle(*g.to_numpy())).all()
+
+
+# -- per-worker counters against a numpy replay ------------------------------
+
+def counter_graph(seed=13, n=1009, chain=40):
+    """A sparse random digraph that trims over a few wide rounds, plus a
+    chain of ``chain`` vertices scattered over the id range (chain vertex
+    i's only arc goes to i+1, the last is a sink), so its tail takes
+    rounds of one death each: rounds an ``auto`` plan compacts."""
+    rng = np.random.default_rng(seed)
+    src, dst = rng.integers(0, n, 2 * n), rng.integers(0, n, 2 * n)
+    ch = rng.permutation(n)[:chain]
+    keep = ~np.isin(src, ch)
+    return CSRGraph.from_edges(n, np.concatenate([src[keep], ch[:-1]]),
+                               np.concatenate([dst[keep], ch[1:]]))
+
+
+@pytest.mark.parametrize("method,frontier", [
+    (meth, f) for meth in METHODS for f in ("dense", "auto", "sparse")
+    if (meth, f) != ("ac3", "sparse")])     # AC-3 has no sparse rounds
+@pytest.mark.parametrize("workers", (1, 3, 16))
+def test_worker_counters_match_bincount_reference(method, frontier,
+                                                  workers):
+    # n = 1009 is no multiple of chunk * workers (nor of AC-6's 64-row
+    # chunks), so the last worker chunk and the compaction pad are partial
+    chunk = 16
+    g = counter_graph()
+    ip, ix = g.to_numpy()
+    wid = worker_of(g.n, workers, chunk)
+    res = plan(g, method=method, workers=workers, chunk=chunk,
+               frontier=frontier).run()
+    rounds = HOST_ROUNDS[method](ip, ix)
+    edges = sum(examined for _, examined in rounds)
+    want = np.bincount(wid, weights=edges, minlength=workers)
+    assert np.array_equal(res.per_worker_edges, want.astype(np.int64))
+    assert res.max_frontier == max(
+        np.bincount(wid[dead], minlength=workers).max()
+        for dead, _ in rounds)
+
+
+@pytest.mark.parametrize("workers", (1, 16))
+def test_ac6_counters_add_no_scatter(workers):
+    """The per-worker counters are reductions: compiled with and without
+    them, AC-6 under an ``auto`` plan has the same scatters."""
+    n, m = 1 << 12, 1 << 16
+    args = (jax.ShapeDtypeStruct((n + 1,), jnp.int32),
+            jax.ShapeDtypeStruct((m,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.int32))
+
+    def scatter_shapes(counters):
+        text = ac6_kernel.lower(
+            *args, workers, counters=counters,
+            frontier=frontier_plan("auto", n, m)).compile().as_text()
+        return collections.Counter(re.findall(r"= (\S+) scatter\(", text))
+
+    plain = scatter_shapes(False)
+    assert plain        # the sparse round's row write-backs are scatters
+    assert scatter_shapes(True) == plain
 
 
 # -- edge cases across methods and backends -----------------------------------

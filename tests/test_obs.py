@@ -5,7 +5,6 @@ oracles on eight graph families, span recording with compile
 attribution, and exporter round-trips."""
 import json
 import os
-from functools import partial
 
 import numpy as np
 import pytest
@@ -15,6 +14,8 @@ from repro.core import CSRGraph, plan, plan_peel, plan_reach, plan_stream
 from repro.core.ref import trim_oracle
 from repro.core.scc import scc_decompose, same_partition, tarjan_oracle
 from repro.graphs import generators
+
+from host_rounds import HOST_ROUNDS, round_totals
 
 
 def _kron(scale, m, seed=1):
@@ -39,42 +40,6 @@ def _families():
         # uniform random arcs, degree 16 (the ``urand`` benchmark shape)
         "urand": generators.erdos_renyi(512, 16 * 512, seed=1),
     }
-
-
-def host_ac4_rounds(indptr, indices, count_init_scan=True):
-    """Host oracle for AC-4's per-round telemetry: synchronous rounds,
-    frontier = newly-zero counters; traversed edges per round = the
-    frontier's in-list scans, with the counter-init scan (all m arcs)
-    charged to round 0 when the method counts it."""
-    n = len(indptr) - 1
-    outdeg = np.diff(indptr).astype(np.int64)
-    m = int(outdeg.sum())
-    indeg = np.zeros(n, np.int64)
-    np.add.at(indeg, indices, 1)
-    order = np.argsort(indices, kind="stable")
-    t_indptr = np.zeros(n + 1, np.int64)
-    np.cumsum(indeg, out=t_indptr[1:])
-    t_indices = np.repeat(np.arange(n), outdeg)[order]
-
-    c = outdeg.copy()
-    dead = np.zeros(n, bool)
-    frontier = c == 0
-    r_frontier, r_edges = [], []
-    while frontier.any():
-        e = int(indeg[frontier].sum())
-        if not r_frontier and count_init_scan:
-            e += m
-        r_frontier.append(int(frontier.sum()))
-        r_edges.append(e)
-        dead |= frontier
-        dec = np.zeros(n, np.int64)
-        for v in np.nonzero(frontier)[0]:
-            np.add.at(dec, t_indices[t_indptr[v]:t_indptr[v + 1]], 1)
-        c = c - dec
-        frontier = (c == 0) & ~dead
-    if not r_frontier and count_init_scan:
-        r_frontier, r_edges = [0], [m]
-    return np.asarray(r_frontier), np.asarray(r_edges)
 
 
 # -- zero-overhead invariant -------------------------------------------------
@@ -106,78 +71,6 @@ def test_instrument_off_bit_identical_no_retrace_no_extra_dispatch():
         assert np.array_equal(np.asarray(r0.status), np.asarray(r2.status))
 
 
-def _host_scan(live, indptr, indices, start, scanning):
-    """Per scanning vertex, the first position at or after ``start`` whose
-    successor is in the round-start snapshot ``live``: (found, position
-    (the degree when exhausted), arcs examined including the hit)."""
-    deg = np.diff(indptr)
-    found = np.zeros(len(deg), bool)
-    pos = deg.copy()
-    examined = np.zeros(len(deg), np.int64)
-    for v in np.nonzero(scanning)[0]:
-        row = indices[indptr[v]:indptr[v + 1]]
-        p = first = min(start[v], deg[v])
-        while p < deg[v] and not live[row[p]]:
-            p += 1
-        found[v] = p < deg[v]
-        pos[v] = p
-        examined[v] = p - first + found[v]
-    return found, pos, examined
-
-
-def host_ac3_rounds(indptr, indices):
-    """Host oracle for AC-3's per-round telemetry: every live vertex
-    re-scans from its pointer (its last support, not past it); a vertex
-    with no successor live at the round's start dies.  The round that
-    kills nothing is the last, and is counted."""
-    n = len(indptr) - 1
-    live = np.ones(n, bool)
-    ptr = np.zeros(n, np.int64)
-    r_frontier, r_edges = [], []
-    while True:
-        found, pos, examined = _host_scan(live, indptr, indices, ptr, live)
-        dead = live & ~found
-        r_frontier.append(int(dead.sum()))
-        r_edges.append(int(examined.sum()))
-        ptr = np.where(live, pos, ptr)
-        live &= found
-        if not dead.any():
-            return np.asarray(r_frontier), np.asarray(r_edges)
-
-
-def host_ac6_rounds(indptr, indices):
-    """Host oracle for AC-6's per-round telemetry: only vertices whose
-    support died scan, strictly past it (every vertex in round 0, from
-    position 0); the rounds run until no live vertex has a dead
-    support."""
-    n = len(indptr) - 1
-    deg = np.diff(indptr)
-    live = np.ones(n, bool)
-    ptr = np.full(n, -1, np.int64)
-    affected = live.copy()
-    r_frontier, r_edges = [], []
-    while affected.any():
-        found, pos, examined = _host_scan(live, indptr, indices, ptr + 1,
-                                          affected)
-        dead = affected & ~found
-        r_frontier.append(int(dead.sum()))
-        r_edges.append(int(examined.sum()))
-        ptr = np.where(affected, pos, ptr)
-        live &= ~dead
-        held = np.nonzero(live & (deg > 0))[0]
-        affected = np.zeros(n, bool)
-        affected[held] = ~live[indices[indptr[held] + ptr[held]]]
-    return np.asarray(r_frontier), np.asarray(r_edges)
-
-
-HOST_ROUNDS = {
-    "ac3": host_ac3_rounds,
-    "ac4": partial(host_ac4_rounds, count_init_scan=True),
-    "ac4*": partial(host_ac4_rounds, count_init_scan=False),
-    "ac6": host_ac6_rounds,
-}
-
-
 # -- device round stats vs host oracle ---------------------------------------
 
 @pytest.mark.parametrize("method", list(HOST_ROUNDS))
@@ -187,7 +80,7 @@ def test_ac4_round_stats_match_host_oracle(family, method):
     g = _families()[family]
     indptr, indices = g.to_numpy()
     rs = plan(g, method=method, instrument=True).run().round_stats
-    hf, he = HOST_ROUNDS[method](indptr, indices)
+    hf, he = round_totals(HOST_ROUNDS[method](indptr, indices))
     pf, pe = rs.per_round("r_frontier"), rs.per_round("r_edges")
     r = len(hf)
     assert np.array_equal(pf[:r], hf), (family, method)
